@@ -65,6 +65,8 @@ def run(arch: str = "minicpm-2b", smoke: bool = True, batches=(2, 4),
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="minicpm-2b")
     ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,

@@ -272,4 +272,6 @@ def run(seed: int = 0):
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     run()

@@ -140,6 +140,8 @@ def run(smoke: bool = True, batch: int = 2, prompt_len: int = 16,
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
                     default=True)

@@ -21,6 +21,11 @@ Checks per recorded dispatch:
   of the linearized (row-major, last-axis-innermost) grid -- the only
   order in which revisiting a tile is accumulation-safe on the
   sequential TPU grid (init at first visit, flush at last).
+- KERNEL-LAYOUT no reshape inside the kernel body changes the minor
+  (lane) dimension.  Mosaic refuses such a shape cast ("unsupported
+  shape cast", e.g. (32, 256) -> (32, 16, 16)), which interpret mode
+  never notices; the macro kernels slice each ADC conversion out of the
+  tile instead.
 
 The sweep covers all five kernel families at every plan design point
 (n_dcim 0-6 x adc 7-9b x L16/32) and every shape recorded in
@@ -84,6 +89,7 @@ class PallasCallRecord:
     scratch_bytes: int
     num_scalar_prefetch: int
     scalar_shapes: List[Tuple[Tuple[int, ...], Any]]
+    kernel_jaxpr: Any = None          # the traced kernel body (KERNEL-LAYOUT)
 
     @property
     def where(self) -> str:
@@ -127,6 +133,10 @@ def record_pallas_calls(records: List[PallasCallRecord]):
 
     def spy(kernel, *, out_shape, grid=None, in_specs=None, out_specs=None,
             grid_spec=None, scratch_shapes=(), **kw):
+        given = dict(grid=grid, in_specs=in_specs, out_specs=out_specs,
+                     grid_spec=grid_spec, scratch_shapes=scratch_shapes)
+        real = orig(kernel, out_shape=out_shape, **kw,
+                    **{k: v for k, v in given.items() if v is not None})
         if grid_spec is not None:
             g = _as_tuple(grid_spec.grid)
             ins, outs = grid_spec.in_specs, grid_spec.out_specs
@@ -156,7 +166,8 @@ def record_pallas_calls(records: List[PallasCallRecord]):
                 name=_kernel_name(kernel), grid=g, specs=specs,
                 scratch_bytes=_scratch_bytes(scratch),
                 num_scalar_prefetch=nsp,
-                scalar_shapes=[(tuple(s.shape), s.dtype) for s in scalars]))
+                scalar_shapes=[(tuple(s.shape), s.dtype) for s in scalars],
+                kernel_jaxpr=_kernel_jaxpr(real, operands)))
             return jax.tree.map(
                 lambda s: jnp.zeros(s.shape, s.dtype), out_shape)
 
@@ -167,6 +178,16 @@ def record_pallas_calls(records: List[PallasCallRecord]):
         yield records
     finally:
         real_pl.pallas_call = orig
+
+
+def _kernel_jaxpr(call, operands):
+    """The kernel body's jaxpr, traced (never lowered) from the real call."""
+    avals = [jax.ShapeDtypeStruct(o.shape, o.dtype) for o in operands]
+    outer = jax.make_jaxpr(call)(*avals)
+    for eqn in outer.jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            return eqn.params["jaxpr"]
+    return None
 
 
 # ---------------------------------------------------------------------------
@@ -312,10 +333,38 @@ def check_grid_aliasing(rec: PallasCallRecord,
                     "accumulated value")
 
 
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs nested in its params
+    (the branches of pl.when, loop bodies)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner)
+
+
+def check_layout(rec: PallasCallRecord, report: AnalysisReport) -> None:
+    if rec.kernel_jaxpr is None:
+        return
+    report.check("KERNEL-LAYOUT")
+    for eqn in _eqns(rec.kernel_jaxpr):
+        if eqn.primitive.name != "reshape":
+            continue
+        src = tuple(eqn.invars[0].aval.shape)
+        dst = tuple(eqn.outvars[0].aval.shape)
+        if max(len(src), len(dst)) >= 2 and src[-1:] != dst[-1:]:
+            report.add("KERNEL-LAYOUT", rec.where,
+                       f"in-kernel reshape {src} -> {dst} changes the lane "
+                       "dim; Mosaic refuses this shape cast on the TPU")
+
+
 def check_record(rec: PallasCallRecord, report: AnalysisReport) -> None:
     check_blocking(rec, report)
     check_vmem(rec, report)
     check_grid_aliasing(rec, report)
+    check_layout(rec, report)
 
 
 # ---------------------------------------------------------------------------

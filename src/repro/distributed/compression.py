@@ -19,16 +19,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.6 exposes shard_map at top level (curried form supported)
-    shard_map = jax.shard_map
-except AttributeError:  # older jax: experimental module, f-first only
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-    def shard_map(f=None, **kwargs):
-        if f is None:  # used as @shard_map(mesh=..., ...) decorator
-            return lambda fn: _shard_map_impl(fn, **kwargs)
-        return _shard_map_impl(f, **kwargs)
-
 Array = jax.Array
 
 
@@ -45,8 +35,7 @@ def compressed_psum_mean(x: Array, err: Array, axis_name: str
     Returns (mean, new_err). new_err is the local quantization residual to
     be added into next step's input (carried in the optimizer state).
     """
-    n = (jax.lax.axis_size(axis_name) if hasattr(jax.lax, "axis_size")
-         else jax.lax.psum(1, axis_name))  # older jax: no lax.axis_size
+    n = jax.lax.axis_size(axis_name)
     xc = x.astype(jnp.float32) + err.astype(jnp.float32)
     q, scale = _quantize(xc)
     new_err = xc - q.astype(jnp.float32) * scale
@@ -67,7 +56,7 @@ def make_compressed_grad_allreduce(mesh: Mesh, axis_name: str = "data"):
         spec = P(*(None,) * g.ndim)
 
         @partial(
-            shard_map, mesh=mesh,
+            jax.shard_map, mesh=mesh,
             in_specs=(spec, spec), out_specs=(spec, spec))
         def _run(gl, el):
             return compressed_psum_mean(gl, el, axis_name)

@@ -12,8 +12,9 @@ hybrid y8 streams (ac, bd, ad, bc) combined digitally as
     y_im += 2^11 * sum_c (y8_ad + y8_bc)
 
 Each sub-MAC uses the same ideal-analog macro arithmetic as
-kernels.ccim_matmul (exact MXU dot + 3 MSB bit-plane dots + 7b mid-tread
-ADC per 16-element chunk), so the result is bit-identical to four
+kernels.ccim_matmul (exact MXU dot + folded MSB bit-plane dots + 7b
+mid-tread ADC per 16-element conversion, each conversion a static slice
+of the tile), so the result is bit-identical to four
 independent ccim_matmul passes -- but with one weight fetch instead of
 four and one kernel launch instead of four.
 """
@@ -26,41 +27,40 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-ACC_LEN = 16
-DCIM_LSB = 2048  # 2^11
-ADC_HALF = 64    # 7-bit bipolar
+from ..ccim_matmul.kernel import (ACC_LEN, DCIM_LSB, SKINNY_SUBLANE,
+                                  _bf16, _conversion_sum, fold_planes,
+                                  signed_bit)
 
 
-def _chunk_dot(x, w):
-    """(C, bm, L) x (C, L, bn) -> (C, bm, bn) int32 batched MXU dot."""
-    return jax.lax.dot_general(
-        x, w,
-        dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.int32,
-    )
+def _sub_mac_sums(xr, xi, wr, wi, wr_planes, wi_planes):
+    """The four per-tile conversion sums (ac, bd, ad, bc) of (a+bi)(c+di).
+
+    ``xr``/``xi`` are int32 activation tiles (bm, bk); ``wr``/``wi`` bf16
+    weight tiles (bk, bn); ``*_planes`` their two folded bf16 DCIM planes
+    (s*(2*b6+b5), s*b6).  Each activation is decomposed ONCE and reused
+    by the two sub-MACs that read it.
+    """
+    def parts(x):
+        return _bf16(x), (_bf16(signed_bit(x, 6)), _bf16(signed_bit(x, 5)))
+
+    def y8(xp, w, planes):
+        x, bits = xp
+        return _conversion_sum(x, w, tuple(zip(bits, planes)))
+
+    a, b = parts(xr), parts(xi)
+    return (y8(a, wr, wr_planes), y8(b, wi, wi_planes),
+            y8(a, wi, wi_planes), y8(b, wr, wr_planes))
 
 
-def _msb_planes(v):
-    """int32 tile -> (value, signed bit-6 plane, signed bit-5 plane)."""
-    s = jnp.where(v < 0, -1, 1)
-    m = jnp.abs(v)
-    return v, s * ((m >> 6) & 1), s * ((m >> 5) & 1)
-
-
-def _y8_chunks(x, x6, x5, w, w6, w5):
-    """Per-chunk hybrid macro output (C, bm, bn) for one real sub-MAC."""
-    exact = _chunk_dot(x, w)
-    dcim = 2 * _chunk_dot(x6, w6) + _chunk_dot(x6, w5) + _chunk_dot(x5, w6)
-    acim = exact - dcim * DCIM_LSB
-    code = jnp.clip(
-        jnp.floor_divide(acim + DCIM_LSB // 2, DCIM_LSB), -ADC_HALF, ADC_HALF - 1
-    )
-    return dcim + code
+def _accumulate(acc_re, acc_im, sums):
+    y_ac, y_bd, y_ad, y_bc = sums
+    acc_re[...] += (y_ac - y_bd) * DCIM_LSB
+    acc_im[...] += (y_ad + y_bc) * DCIM_LSB
 
 
 def _ccim_complex_kernel(
     xr_ref, xi_ref, wr_ref, wi_ref, or_ref, oi_ref, acc_re, acc_im,
-    *, bk: int, n_k: int,
+    *, n_k: int,
 ):
     """One (bm, bn) Re tile AND one Im tile; grid axis 2 walks K in bk steps."""
     @pl.when(pl.program_id(2) == 0)
@@ -70,26 +70,12 @@ def _ccim_complex_kernel(
 
     # ONE residency of the co-located (Re, Im) weight tile + ONE bit-plane
     # decomposition, shared by all four sub-MACs below.
-    wr, wr6, wr5 = _msb_planes(wr_ref[...].astype(jnp.int32))   # (bk, bn)
-    wi, wi6, wi5 = _msb_planes(wi_ref[...].astype(jnp.int32))
-    xr, xr6, xr5 = _msb_planes(xr_ref[...].astype(jnp.int32))   # (bm, bk)
-    xi, xi6, xi5 = _msb_planes(xi_ref[...].astype(jnp.int32))
-
-    bm, bn = xr.shape[0], wr.shape[1]
-    c = bk // ACC_LEN
-    to_xc = lambda v: v.reshape(bm, c, ACC_LEN).swapaxes(0, 1)  # (C, bm, L)
-    to_wc = lambda v: v.reshape(c, ACC_LEN, bn)                 # (C, L, bn)
-    xrc = tuple(map(to_xc, (xr, xr6, xr5)))
-    xic = tuple(map(to_xc, (xi, xi6, xi5)))
-    wrc = tuple(map(to_wc, (wr, wr6, wr5)))
-    wic = tuple(map(to_wc, (wi, wi6, wi5)))
-
-    y_ac = _y8_chunks(*xrc, *wrc)
-    y_bd = _y8_chunks(*xic, *wic)
-    y_ad = _y8_chunks(*xrc, *wic)
-    y_bc = _y8_chunks(*xic, *wrc)
-    acc_re[...] += jnp.sum(y_ac - y_bd, axis=0) * DCIM_LSB
-    acc_im[...] += jnp.sum(y_ad + y_bc, axis=0) * DCIM_LSB
+    wr = wr_ref[...].astype(jnp.int32)                          # (bk, bn)
+    wi = wi_ref[...].astype(jnp.int32)
+    planes = lambda w: tuple(map(_bf16, fold_planes(w)))
+    _accumulate(acc_re, acc_im, _sub_mac_sums(
+        xr_ref[...].astype(jnp.int32), xi_ref[...].astype(jnp.int32),
+        _bf16(wr), _bf16(wi), planes(wr), planes(wi)))
 
     @pl.when(pl.program_id(2) == n_k - 1)
     def _done():
@@ -97,22 +83,9 @@ def _ccim_complex_kernel(
         oi_ref[...] = acc_im[...]
 
 
-def _y8_chunks_folded(x, x6, x5, w, wp6, wp5):
-    """Per-chunk hybrid output with prepacked folded weight planes:
-    dcim = x6 . (s*(2*b6+b5)) + x5 . (s*b6) -- integer-identical to the
-    3-dot form in ``_y8_chunks``."""
-    exact = _chunk_dot(x, w)
-    dcim = _chunk_dot(x6, wp6) + _chunk_dot(x5, wp5)
-    acim = exact - dcim * DCIM_LSB
-    code = jnp.clip(
-        jnp.floor_divide(acim + DCIM_LSB // 2, DCIM_LSB), -ADC_HALF, ADC_HALF - 1
-    )
-    return dcim + code
-
-
 def _ccim_complex_kernel_prepacked(
     xr_ref, xi_ref, wr_ref, wi_ref, wr6_ref, wr5_ref, wi6_ref, wi5_ref,
-    or_ref, oi_ref, acc_re, acc_im, *, bk: int, n_k: int,
+    or_ref, oi_ref, acc_re, acc_im, *, n_k: int,
 ):
     """Prepacked-weight fused complex kernel: the co-located (Re, Im)
     weight tiles AND their folded MSB planes stream in as inputs (packed
@@ -124,38 +97,16 @@ def _ccim_complex_kernel_prepacked(
         acc_re[...] = jnp.zeros_like(acc_re)
         acc_im[...] = jnp.zeros_like(acc_im)
 
-    wr = wr_ref[...].astype(jnp.int32)                          # (bk, bn)
-    wi = wi_ref[...].astype(jnp.int32)
-    wr6, wr5 = wr6_ref[...].astype(jnp.int32), wr5_ref[...].astype(jnp.int32)
-    wi6, wi5 = wi6_ref[...].astype(jnp.int32), wi5_ref[...].astype(jnp.int32)
-    xr, xr6, xr5 = _msb_planes(xr_ref[...].astype(jnp.int32))   # (bm, bk)
-    xi, xi6, xi5 = _msb_planes(xi_ref[...].astype(jnp.int32))
-
-    bm, bn = xr.shape[0], wr.shape[1]
-    c = bk // ACC_LEN
-    to_xc = lambda v: v.reshape(bm, c, ACC_LEN).swapaxes(0, 1)  # (C, bm, L)
-    to_wc = lambda v: v.reshape(c, ACC_LEN, bn)                 # (C, L, bn)
-    xrc = tuple(map(to_xc, (xr, xr6, xr5)))
-    xic = tuple(map(to_xc, (xi, xi6, xi5)))
-    wrc = tuple(map(to_wc, (wr, wr6, wr5)))
-    wic = tuple(map(to_wc, (wi, wi6, wi5)))
-
-    y_ac = _y8_chunks_folded(*xrc, *wrc)
-    y_bd = _y8_chunks_folded(*xic, *wic)
-    y_ad = _y8_chunks_folded(*xrc, *wic)
-    y_bc = _y8_chunks_folded(*xic, *wrc)
-    acc_re[...] += jnp.sum(y_ac - y_bd, axis=0) * DCIM_LSB
-    acc_im[...] += jnp.sum(y_ad + y_bc, axis=0) * DCIM_LSB
+    tile = lambda r: _bf16(r[...].astype(jnp.int32))
+    _accumulate(acc_re, acc_im, _sub_mac_sums(
+        xr_ref[...].astype(jnp.int32), xi_ref[...].astype(jnp.int32),
+        tile(wr_ref), tile(wi_ref),
+        (tile(wr6_ref), tile(wr5_ref)), (tile(wi6_ref), tile(wi5_ref))))
 
     @pl.when(pl.program_id(2) == n_k - 1)
     def _done():
         or_ref[...] = acc_re[...]
         oi_ref[...] = acc_im[...]
-
-
-# int8 sublane tile the skinny path pads M to: ONE definition shared with
-# the real-valued kernels (the padding contract must match dispatch-wide)
-from ..ccim_matmul.kernel import SKINNY_SUBLANE  # noqa: E402
 
 
 def _ccim_complex_kernel_prepacked_skinny(
@@ -168,34 +119,19 @@ def _ccim_complex_kernel_prepacked_skinny(
     tile (sliced in-kernel per k step), so only the co-located (Re, Im)
     weight tiles stream with k -- double-buffered by the Pallas pipeline.
     Bit-identical to ``_ccim_complex_kernel_prepacked``."""
-    @pl.when(pl.program_id(1) == 0)
+    k_step = pl.program_id(1)
+
+    @pl.when(k_step == 0)
     def _init():
         acc_re[...] = jnp.zeros_like(acc_re)
         acc_im[...] = jnp.zeros_like(acc_im)
 
-    k_step = pl.program_id(1)
-    wr = wr_ref[...].astype(jnp.int32)                          # (bk, bn)
-    wi = wi_ref[...].astype(jnp.int32)
-    sl = lambda i: planes_ref[i, pl.ds(k_step * bk, bk), :].astype(jnp.int32)
-    wr6, wr5, wi6, wi5 = sl(0), sl(1), sl(2), sl(3)
-    xr, xr6, xr5 = _msb_planes(xr_ref[...].astype(jnp.int32))   # (Mp, bk)
-    xi, xi6, xi5 = _msb_planes(xi_ref[...].astype(jnp.int32))
-
-    bm, bn = xr.shape[0], wr.shape[1]
-    c = bk // ACC_LEN
-    to_xc = lambda v: v.reshape(bm, c, ACC_LEN).swapaxes(0, 1)  # (C, Mp, L)
-    to_wc = lambda v: v.reshape(c, ACC_LEN, bn)                 # (C, L, bn)
-    xrc = tuple(map(to_xc, (xr, xr6, xr5)))
-    xic = tuple(map(to_xc, (xi, xi6, xi5)))
-    wrc = tuple(map(to_wc, (wr, wr6, wr5)))
-    wic = tuple(map(to_wc, (wi, wi6, wi5)))
-
-    y_ac = _y8_chunks_folded(*xrc, *wrc)
-    y_bd = _y8_chunks_folded(*xic, *wic)
-    y_ad = _y8_chunks_folded(*xrc, *wic)
-    y_bc = _y8_chunks_folded(*xic, *wrc)
-    acc_re[...] += jnp.sum(y_ac - y_bd, axis=0) * DCIM_LSB
-    acc_im[...] += jnp.sum(y_ad + y_bc, axis=0) * DCIM_LSB
+    tile = lambda v: _bf16(v.astype(jnp.int32))
+    sl = lambda i: tile(planes_ref[i, pl.ds(k_step * bk, bk), :])
+    _accumulate(acc_re, acc_im, _sub_mac_sums(
+        xr_ref[...].astype(jnp.int32), xi_ref[...].astype(jnp.int32),
+        tile(wr_ref[...]), tile(wi_ref[...]),
+        (sl(0), sl(1)), (sl(2), sl(3))))
 
     @pl.when(k_step == n_k - 1)
     def _done():
@@ -274,7 +210,7 @@ def ccim_complex_matmul_prepacked_pallas(
     assert bk % ACC_LEN == 0
     n_k = K // bk
 
-    kernel = functools.partial(_ccim_complex_kernel_prepacked, bk=bk, n_k=n_k)
+    kernel = functools.partial(_ccim_complex_kernel_prepacked, n_k=n_k)
     x_spec = pl.BlockSpec((bm, bk), lambda i, j, k: (i, k))
     w_spec = pl.BlockSpec((bk, bn), lambda i, j, k: (k, j))
     o_spec = pl.BlockSpec((bm, bn), lambda i, j, k: (i, j))
@@ -318,7 +254,7 @@ def ccim_complex_matmul_pallas(
     assert bk % ACC_LEN == 0
     n_k = K // bk
 
-    kernel = functools.partial(_ccim_complex_kernel, bk=bk, n_k=n_k)
+    kernel = functools.partial(_ccim_complex_kernel, n_k=n_k)
     x_spec = pl.BlockSpec((bm, bk), lambda i, j, k: (i, k))
     w_spec = pl.BlockSpec((bk, bn), lambda i, j, k: (k, j))
     o_spec = pl.BlockSpec((bm, bn), lambda i, j, k: (i, j))

@@ -4,7 +4,7 @@ TPU adaptation of the paper's dataflow (see DESIGN.md §2): the MXU plays the
 role of the bit-parallel array.  Per 16-element K-chunk ("one ADC
 conversion") we compute
 
-    exact_c = x_c . w_c                       (int8 x int8 -> int32, MXU)
+    exact_c = x_c . w_c                       (MXU dot, exact in f32)
     dcim_c  = 2*x6.w6 + x6.w5 + x5.w6         (3 signed MSB bit-plane dots)
     acim_c  = exact_c - 2^11 * dcim_c         (the analog group's ideal sum)
     code_c  = clip(floor(acim_c/2^11 + 1/2), -64, 63)     (7b SAR ADC)
@@ -14,8 +14,17 @@ conversion") we compute
 i.e. the *ideal-analog* bit-true macro arithmetic (mismatch noise is a
 training-time emulation feature injected at the jnp level, see core.qat;
 the silicon itself has frozen mismatch -- the kernel models the design
-arithmetic).  All chunk dots are expressed as one batched dot_general so
-the MXU sees (C, bm, 16) x (C, 16, bn).
+arithmetic).
+
+Conversion layout: each conversion is a STATIC slice of the tile -- an
+acc_len-wide lane slice of the activations and an acc_len-row sublane
+slice of the weights -- walked by an unrolled Python loop
+(``_conversion_sum``).  Mosaic refuses the reshape that would split the
+lane axis into (C, L), so no kernel here reshapes a tile.  Operands go to
+the MXU as bf16 with f32 accumulation: they are integers with |v| <= 127
+(exact in bf16) and one conversion sums at most 64 products, at most
+64 * 127^2 < 2^24, so every dot is exact and the int32 epilogue
+(floor-divide, clip) sees the same integers as the jnp oracle.
 
 Block shapes are MXU/VMEM aligned: bm, bn multiples of 128 (lane dim), bk a
 multiple of acc_len; VMEM working set = bm*bk + bk*bn (int8) + bm*bn
@@ -35,16 +44,65 @@ DCIM_LSB = 2048  # 2^11
 ADC_HALF = 64    # 7-bit bipolar
 
 
-def _chunk_dot(x, w):
-    """(C, bm, L) x (C, L, bn) -> (C, bm, bn) int32 batched MXU dot."""
+def _dot(x, w):
+    """(bm, L) x (L, bn) bf16 -> (bm, bn) int32; exact (module docstring)."""
     return jax.lax.dot_general(
         x, w,
-        dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-        preferred_element_type=jnp.int32,
-    )
+        dimension_numbers=(((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ).astype(jnp.int32)
 
 
-def _ccim_kernel(x_ref, w_ref, o_ref, acc_ref, *, bk: int, n_k: int):
+def signed_bit(v, j: int):
+    """Signed bit-plane j of an int32 tile: sign(v) * bit_j(|v|)."""
+    return jnp.where(v < 0, -1, 1) * ((jnp.abs(v) >> j) & 1)
+
+
+def fold_planes(w):
+    """int32 weight tile -> the two folded prototype DCIM planes,
+    s*(2*b6+b5) (pairs with x bit 6) and s*b6 (pairs with x bit 5):
+    2*x6.w6 + x6.w5 + x5.w6 == x6.(2*w6+w5) + x5.w6 in integers."""
+    w6, w5 = signed_bit(w, 6), signed_bit(w, 5)
+    return 2 * w6 + w5, w6
+
+
+def _bf16(v):
+    return v.astype(jnp.bfloat16)
+
+
+def _dcim_pairs(x, planes, x_bits):
+    """(activation bit-plane j, folded weight plane) bf16 tile pairs: the
+    operands of the digital term, one pair per x bit in ``x_bits``."""
+    return tuple((_bf16(signed_bit(x, j)), _bf16(p))
+                 for p, j in zip(planes, x_bits))
+
+
+def _conversion_sum(x, w, dcim_pairs=(), *, acc_len: int = ACC_LEN,
+                    dcim_lsb: int = DCIM_LSB, adc_half: int = ADC_HALF):
+    """sum_c y8_c over the acc_len-wide conversions of one tile -> int32.
+
+    ``x`` (bm, bk) and ``w`` (bk, bn) are the integer operand tiles;
+    ``dcim_pairs`` lists (activation bit-plane (bm, bk), folded weight
+    plane (bk, bn)) tiles whose dots make up the digital term.  All
+    operands are bf16 holding integers (see the module docstring).
+    """
+    total = None
+    for c in range(x.shape[1] // acc_len):
+        s = slice(c * acc_len, (c + 1) * acc_len)
+        exact = _dot(x[:, s], w[s, :])
+        dcim = jnp.zeros_like(exact)
+        for xj, pj in dcim_pairs:
+            dcim = dcim + _dot(xj[:, s], pj[s, :])
+        code = jnp.clip(
+            jnp.floor_divide(exact - dcim * dcim_lsb + dcim_lsb // 2,
+                             dcim_lsb),
+            -adc_half, adc_half - 1)
+        y8 = dcim + code
+        total = y8 if total is None else total + y8
+    return total
+
+
+def _ccim_kernel(x_ref, w_ref, o_ref, acc_ref, *, n_k: int):
     """One (bm, bn) output tile; grid axis 2 walks K in bk steps."""
     @pl.when(pl.program_id(2) == 0)
     def _init():
@@ -52,44 +110,17 @@ def _ccim_kernel(x_ref, w_ref, o_ref, acc_ref, *, bk: int, n_k: int):
 
     x = x_ref[...].astype(jnp.int32)            # (bm, bk)
     w = w_ref[...].astype(jnp.int32)            # (bk, bn)
-    bm, bn = x.shape[0], w.shape[1]
-    c = bk // ACC_LEN
-
-    # sign / magnitude decomposition (SMF)
-    sx = jnp.where(x < 0, -1, 1)
-    mx = jnp.abs(x)
-    sw = jnp.where(w < 0, -1, 1)
-    mw = jnp.abs(w)
-
-    # signed MSB bit-planes (values in {-1, 0, +1})
-    x6 = sx * ((mx >> 6) & 1)
-    x5 = sx * ((mx >> 5) & 1)
-    w6 = sw * ((mw >> 6) & 1)
-    w5 = sw * ((mw >> 5) & 1)
-
-    xc = x.reshape(bm, c, ACC_LEN).swapaxes(0, 1)       # (C, bm, L)
-    wc = w.reshape(c, ACC_LEN, bn)                      # (C, L, bn)
-    exact = _chunk_dot(xc, wc)
-
-    x6c = x6.reshape(bm, c, ACC_LEN).swapaxes(0, 1)
-    x5c = x5.reshape(bm, c, ACC_LEN).swapaxes(0, 1)
-    w6c = w6.reshape(c, ACC_LEN, bn)
-    w5c = w5.reshape(c, ACC_LEN, bn)
-    dcim = 2 * _chunk_dot(x6c, w6c) + _chunk_dot(x6c, w5c) + _chunk_dot(x5c, w6c)
-
-    acim = exact - dcim * DCIM_LSB
-    code = jnp.clip(
-        jnp.floor_divide(acim + DCIM_LSB // 2, DCIM_LSB), -ADC_HALF, ADC_HALF - 1
-    )
-    y8 = dcim + code
-    acc_ref[...] += jnp.sum(y8, axis=0) * DCIM_LSB
+    # sign / magnitude decomposition (SMF): signed MSB bit-planes, the
+    # weight side folded so the digital term is two dots per conversion
+    pairs = _dcim_pairs(x, fold_planes(w), (6, 5))
+    acc_ref[...] += _conversion_sum(_bf16(x), _bf16(w), pairs) * DCIM_LSB
 
     @pl.when(pl.program_id(2) == n_k - 1)
     def _done():
         o_ref[...] = acc_ref[...]
 
 
-def _ccim_kernel_prepacked(*refs, bk: int, n_k: int, acc_len: int,
+def _ccim_kernel_prepacked(*refs, n_k: int, acc_len: int,
                            x_bits: tuple, dcim_lsb: int, adc_half: int):
     """Prepacked-weight variant, generalized over the macro's D/A split.
 
@@ -119,31 +150,12 @@ def _ccim_kernel_prepacked(*refs, bk: int, n_k: int, acc_len: int,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     x = x_ref[...].astype(jnp.int32)            # (bm, bk)
-    w = w_ref[...].astype(jnp.int32)            # (bk, bn)
-    bm, bn = x.shape[0], w.shape[1]
-    c = bk // acc_len
-
-    to_xc = lambda v: v.reshape(bm, c, acc_len).swapaxes(0, 1)  # (C, bm, L)
-    to_wc = lambda v: v.reshape(c, acc_len, bn)                 # (C, L, bn)
-    exact = _chunk_dot(to_xc(x), to_wc(w))
-
     # activation-side decomposition only (activations stream, as in silicon)
-    dcim = jnp.zeros_like(exact)
-    if x_bits:
-        sx = jnp.where(x < 0, -1, 1)
-        mx = jnp.abs(x)
-        planes = planes_ref[...].astype(jnp.int32)  # (n_planes, bk, bn)
-        for i, j in enumerate(x_bits):
-            xj = sx * ((mx >> j) & 1)
-            dcim = dcim + _chunk_dot(to_xc(xj), to_wc(planes[i]))
-
-    acim = exact - dcim * dcim_lsb
-    code = jnp.clip(
-        jnp.floor_divide(acim + dcim_lsb // 2, dcim_lsb),
-        -adc_half, adc_half - 1,
-    )
-    y8 = dcim + code
-    acc_ref[...] += jnp.sum(y8, axis=0) * dcim_lsb
+    planes = [planes_ref[i].astype(jnp.int32) for i in range(len(x_bits))]
+    acc_ref[...] += _conversion_sum(
+        _bf16(x), _bf16(w_ref[...].astype(jnp.int32)),
+        _dcim_pairs(x, planes, x_bits),
+        acc_len=acc_len, dcim_lsb=dcim_lsb, adc_half=adc_half) * dcim_lsb
 
     @pl.when(pl.program_id(2) == n_k - 1)
     def _done():
@@ -183,7 +195,7 @@ def ccim_matmul_prepacked_pallas(
     n_k = K // bk
 
     kernel = functools.partial(
-        _ccim_kernel_prepacked, bk=bk, n_k=n_k, acc_len=acc_len,
+        _ccim_kernel_prepacked, n_k=n_k, acc_len=acc_len,
         x_bits=tuple(x_bits), dcim_lsb=dcim_lsb, adc_half=adc_half)
     in_specs = [pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),
                 pl.BlockSpec((bk, bn), lambda i, j, k: (k, j))]
@@ -238,30 +250,13 @@ def _ccim_kernel_prepacked_skinny(*refs, bk: int, n_k: int, acc_len: int,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     x = x_ref[...].astype(jnp.int32)            # (Mp, bk)
-    w = w_ref[...].astype(jnp.int32)            # (bk, bn)
-    bm, bn = x.shape[0], w.shape[1]
-    c = bk // acc_len
-
-    to_xc = lambda v: v.reshape(bm, c, acc_len).swapaxes(0, 1)  # (C, Mp, L)
-    to_wc = lambda v: v.reshape(c, acc_len, bn)                 # (C, L, bn)
-    exact = _chunk_dot(to_xc(x), to_wc(w))
-
-    dcim = jnp.zeros_like(exact)
-    if x_bits:
-        sx = jnp.where(x < 0, -1, 1)
-        mx = jnp.abs(x)
-        for i, j in enumerate(x_bits):
-            xj = sx * ((mx >> j) & 1)
-            # K-resident planes: slice this k step's rows in-register
-            pj = planes_ref[i, pl.ds(k_step * bk, bk), :].astype(jnp.int32)
-            dcim = dcim + _chunk_dot(to_xc(xj), to_wc(pj))
-
-    acim = exact - dcim * dcim_lsb
-    code = jnp.clip(
-        jnp.floor_divide(acim + dcim_lsb // 2, dcim_lsb),
-        -adc_half, adc_half - 1,
-    )
-    acc_ref[...] += jnp.sum(dcim + code, axis=0) * dcim_lsb
+    # K-resident planes: slice this k step's rows
+    planes = [planes_ref[i, pl.ds(k_step * bk, bk), :].astype(jnp.int32)
+              for i in range(len(x_bits))]
+    acc_ref[...] += _conversion_sum(
+        _bf16(x), _bf16(w_ref[...].astype(jnp.int32)),
+        _dcim_pairs(x, planes, x_bits),
+        acc_len=acc_len, dcim_lsb=dcim_lsb, adc_half=adc_half) * dcim_lsb
 
     @pl.when(k_step == n_k - 1)
     def _done():
@@ -341,7 +336,7 @@ def ccim_matmul_pallas(
     assert bk % ACC_LEN == 0
     n_k = K // bk
 
-    kernel = functools.partial(_ccim_kernel, bk=bk, n_k=n_k)
+    kernel = functools.partial(_ccim_kernel, n_k=n_k)
     return pl.pallas_call(
         kernel,
         grid=(M // bm, N // bn, n_k),

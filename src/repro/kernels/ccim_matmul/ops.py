@@ -51,12 +51,24 @@ def _pick_k_block(c: int, preferred: int = 32) -> int:
     return b
 
 
+def _pick_k_tile(k_dim: int, acc_len: int) -> tuple[int, int]:
+    """(bk, Kp): the K block and the padded K it tiles.
+
+    bk is a whole number of conversions and, on the TPU, a multiple of the
+    128-lane tile -- or, where no such block keeps the padding waste under
+    25% (K under ~480), the whole padded K in one grid step, which a block
+    spanning the full axis is allowed to be."""
+    kc = _pad_to(k_dim, acc_len)
+    bk = _pick_k_block(kc // acc_len) * acc_len
+    if bk % 128:
+        bk = kc
+    return bk, _pad_to(kc, bk)
+
+
 def pick_gemm_blocks(M: int, N: int, K: int,
                      acc_len: int = ACC_LEN) -> tuple[int, int, int]:
     """(bm, bn, bk) for an (M, K) x (K, N) macro GEMM; K in acc_len chunks."""
-    bm, bn = _pick_block(M, 128), _pick_block(N, 128)
-    bk = _pick_k_block(_pad_to(K, acc_len) // acc_len) * acc_len
-    return bm, bn, bk
+    return _pick_block(M, 128), _pick_block(N, 128), _pick_k_tile(K, acc_len)[0]
 
 
 def pick_weight_blocks(K: int, N: int,
@@ -70,8 +82,8 @@ def pick_weight_blocks(K: int, N: int,
     assigns non-prototype lengths per projection).
     """
     bn = _pick_block(N, 128)
-    bk = _pick_k_block(_pad_to(K, acc_len) // acc_len) * acc_len
-    return bn, bk, _pad_to(N, bn), _pad_to(_pad_to(K, acc_len), bk)
+    bk, Kp = _pick_k_tile(K, acc_len)
+    return bn, bk, _pad_to(N, bn), Kp
 
 
 def ccim_matmul_int_prepacked(

@@ -13,6 +13,12 @@ For each cell we record:
 and write JSON to results/dryrun/. Any sharding mismatch, OOM-at-compile or
 unsupported collective is a bug in the framework and fails the cell.
 
+CPU only: this never runs on a TPU.  It forces 512 placeholder host
+devices, and ``--all`` imports JAX in the parent and then starts one child
+JAX process per cell -- on a chip the parent would hold the device and
+every child would fail or hang.  No chip path (``chip_smoke.py``, the
+serving launchers) imports it.
+
 Usage:
   python -m repro.launch.dryrun --arch qwen3-14b --shape train_4k
   python -m repro.launch.dryrun --arch qwen3-14b --shape train_4k --multi-pod

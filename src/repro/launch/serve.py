@@ -39,6 +39,7 @@ from ..configs import ARCHS, get_config
 from ..data import DataConfig, batch_at
 from ..models import lm
 from ..obs import REGISTRY, ObsConfig, get_tracer, set_trace_path, span
+from .compile_cache import enable_compile_cache
 from .paging import PagedLayout
 from .scheduler import (ContinuousBatchingScheduler, mixed_length_requests,
                         sampling_key)
@@ -672,6 +673,7 @@ def serve_guarded(arch: str, smoke: bool = True, slots: int = 2,
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=ARCHS, required=True)
     ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,

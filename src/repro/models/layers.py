@@ -295,11 +295,8 @@ def attention_init(key, cfg: ModelConfig, dtype=jnp.bfloat16):
 
 
 def _ambient_mesh():
-    try:
-        from jax._src.mesh import thread_resources
-        mesh = thread_resources.env.physical_mesh
-    except Exception:  # pragma: no cover - jax internals moved
-        return None
+    from jax._src.mesh import thread_resources
+    mesh = thread_resources.env.physical_mesh
     return None if mesh.empty else mesh
 
 

@@ -37,9 +37,8 @@ def _rules(report):
 
 
 def test_trace_f64_fires():
-    from jax.experimental import enable_x64
     rep = AnalysisReport()
-    with enable_x64():
+    with jax.enable_x64(True):
         jaxpr = jax.make_jaxpr(lambda x: x * 2.0)(
             jnp.arange(4, dtype=jnp.float64))
         AT.check_no_f64("seeded", jaxpr, rep)
@@ -195,13 +194,44 @@ def test_kernel_race_clean_on_accumulation_order():
     assert rep.passed
 
 
+def _spied(kernel, in_shape, out_shape):
+    from jax.experimental import pallas as pl
+    records = []
+    sds = lambda s: jax.ShapeDtypeStruct(s, jnp.float32)
+    with AK.record_pallas_calls(records):
+        jax.eval_shape(lambda x: pl.pallas_call(
+            kernel, out_shape=sds(out_shape), interpret=True)(x),
+            sds(in_shape))
+    return records[0]
+
+
+def test_kernel_layout_fires_on_lane_split_reshape():
+    # the pre-bring-up conversion split: (8, 128) -> (8, 8, 16) splits the
+    # lane axis, which Mosaic refuses ("unsupported shape cast")
+    def guilty(x_ref, o_ref):
+        o_ref[...] = x_ref[...].reshape(8, 8, 16).sum(axis=1)
+    rep = AnalysisReport()
+    AK.check_layout(_spied(guilty, (8, 128), (8, 16)), rep)
+    assert "KERNEL-LAYOUT" in _rules(rep)
+
+
+def test_kernel_layout_clean_on_conversion_slices():
+    # the same per-conversion sum from static lane slices
+    def clean(x_ref, o_ref):
+        x = x_ref[...]
+        o_ref[...] = sum(x[:, c * 16:(c + 1) * 16] for c in range(8))
+    rep = AnalysisReport()
+    AK.check_layout(_spied(clean, (8, 128), (8, 16)), rep)
+    assert rep.passed and rep.checked["KERNEL-LAYOUT"] == 1
+
+
 def test_spy_captures_real_dispatch():
     records = []
     AK.capture_ccim_matmul(records, M=4, K=256, N=256,
                            cfg=AK.CCIMConfig())
     assert records, "spy saw no pallas_call on the skinny decode path"
     rec = records[0]
-    assert rec.grid and rec.specs
+    assert rec.grid and rec.specs and rec.kernel_jaxpr is not None
     rep = AnalysisReport()
     AK.check_record(rec, rep)
     assert rep.passed

@@ -5,8 +5,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from repro.distributed import compressed_psum_mean, dp_axes, spec_for
-from repro.distributed.compression import (make_compressed_grad_allreduce,
-                                            shard_map)
+from repro.distributed.compression import make_compressed_grad_allreduce
 
 
 class FakeMesh:
@@ -76,7 +75,7 @@ def test_compressed_psum_identity_on_single_shard():
     g = jnp.linspace(-1, 1, 64).reshape(8, 8)
     e = jnp.zeros_like(g)
 
-    @shard_map(mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()))
+    @jax.shard_map(mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()))
     def run(gl, el):
         return compressed_psum_mean(gl, el, "data")
 
@@ -95,7 +94,7 @@ def test_error_feedback_reduces_bias_over_steps():
     e = jnp.zeros_like(g)
     acc_true, acc_comp = 0.0, 0.0
 
-    @shard_map(mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()))
+    @jax.shard_map(mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()))
     def run(gl, el):
         return compressed_psum_mean(gl, el, "data")
 
