@@ -182,6 +182,7 @@ def ccim_complex_matmul_prepacked_skinny_pallas(
             pltpu.VMEM((Mp, bn), jnp.int32),
         ],
         interpret=interpret,
+        name="ccim_complex_matmul_prepacked_skinny_pallas",
     )(x_re, x_im, w_re, w_im, planes)
 
 
@@ -228,6 +229,7 @@ def ccim_complex_matmul_prepacked_pallas(
             pltpu.VMEM((bm, bn), jnp.int32),
         ],
         interpret=interpret,
+        name="ccim_complex_matmul_prepacked_pallas",
     )(x_re, x_im, w_re, w_im, wr_p6, wr_p5, wi_p6, wi_p5)
 
 
@@ -272,4 +274,5 @@ def ccim_complex_matmul_pallas(
             pltpu.VMEM((bm, bn), jnp.int32),
         ],
         interpret=interpret,
+        name="ccim_complex_matmul_pallas",
     )(x_re, x_im, w_re, w_im)

@@ -212,6 +212,7 @@ def ccim_matmul_prepacked_pallas(
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.int32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
+        name="ccim_matmul_prepacked_pallas",
     )(*operands)
 
 
@@ -313,6 +314,7 @@ def ccim_matmul_prepacked_skinny_pallas(
         out_shape=jax.ShapeDtypeStruct((Mp, N), jnp.int32),
         scratch_shapes=[pltpu.VMEM((Mp, bn), jnp.int32)],
         interpret=interpret,
+        name="ccim_matmul_prepacked_skinny_pallas",
     )(*operands)
 
 
@@ -348,4 +350,5 @@ def ccim_matmul_pallas(
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.int32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
+        name="ccim_matmul_pallas",
     )(x_q, w_q)

@@ -62,4 +62,5 @@ def int8_matmul_pallas(
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
         interpret=interpret,
+        name="int8_matmul_pallas",
     )(x_q, w_q, sx, sw)

@@ -123,5 +123,6 @@ def paged_attention_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, Dh), q.dtype),
         interpret=interpret,
+        name="paged_attention_pallas",
     )(table.astype(jnp.int32), lengths.astype(jnp.int32),
       jnp.asarray(is_local, jnp.int32).reshape(1), q, k_pool, v_pool)

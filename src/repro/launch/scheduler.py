@@ -91,7 +91,10 @@ from ..models import lm
 from ..models.config import ModelConfig
 from ..obs import taps
 from ..obs import rings as obs_rings
+from ..obs.compiles import backend_compiles
 from ..obs.rings import ObsConfig, ObsSnapshot
+from ..obs.scopes import scoped
+from ..obs.trace import get_tracer, span
 from ..resilience import faults as rfaults
 from .paging import PagedLayout, cdiv, contiguous_kv_bytes, plan_prefix_sharing
 
@@ -136,6 +139,10 @@ class ServeReport:
     n_pf: int = 0                 # chunked-prefill iterations (paged mode)
     peak_blocks: int = 0          # peak live pool blocks (paged mode)
     obs: Optional[ObsSnapshot] = None   # harvested device rings (obs mode)
+    n_iter: int = 0               # loop iterations, harvests included
+    n_compiles: int = 0           # XLA backend compiles during run()
+    # host seconds of each span closed inside run() (sched.*, launch.*)
+    host_s: Dict[str, float] = dataclasses.field(default_factory=dict)
 
     @property
     def total_tokens(self) -> int:
@@ -341,8 +348,10 @@ class ContinuousBatchingScheduler:
             self.prefill_chunk = prompt_len
             self._p_pad = prompt_len
         self._loops: Dict[int, object] = {}    # queue length -> executable
+        self._n_runs = 0                       # run() calls: batch serial
         self._iters: Dict[int, object] = {}    # queue length -> one-iter exe
 
+        @scoped("sched.sample")
         def sample(logits, keys):
             """logits (R, V) f32, keys (R, 2) -> (R,) int32 tokens."""
             if temperature <= 0:
@@ -567,9 +576,15 @@ class ContinuousBatchingScheduler:
         The while_loop body (``_build_loop``) and the host-stepped
         instrumented runner (``run_instrumented``) share this function,
         so instrumenting never measures a different program.  Returns
-        (carry, branch, continue?)."""
+        (carry, branch, continue?).
+
+        Each branch runs under its named scope (``sched.harvest``,
+        ``sched.admit``, ``sched.prefill``, ``sched.step``), which the
+        compiled loop's HLO metadata carries down to every op
+        (``compiled_loop_text``); scopes add no op."""
         cfg, paged = self.cfg, self.paged
 
+        @scoped("sched.harvest")
         def harvest(c):
             st = c["st"]
             slot = jnp.argmax(st["pending"])
@@ -599,6 +614,7 @@ class ContinuousBatchingScheduler:
             c["st"] = st
             return c
 
+        @scoped("sched.admit")
         def admit_contiguous(c):
             st, qidx = c["st"], c["q_head"]
             slot = jnp.argmin(self._occupied(st))
@@ -629,6 +645,7 @@ class ContinuousBatchingScheduler:
                         res_first=c["res_first"].at[qidx].set(c["n_iter"]),
                         **upd)
 
+        @scoped("sched.admit")
         def admit_paged(c):
             """Grant blocks + arm the slot; the prompt streams in through
             the prefill branch.  The free list is the refcount vector
@@ -694,6 +711,7 @@ class ContinuousBatchingScheduler:
                         peak_blocks=jnp.maximum(c["peak_blocks"], used),
                         **upd)
 
+        @scoped("sched.prefill")
         def prefill_chunk(c):
             """Advance the first filling slot by one chunk; the final
             chunk samples the first token exactly as arm_slot would
@@ -787,6 +805,7 @@ class ContinuousBatchingScheduler:
             return dict(c, st=self._step_fn(params, c["st"]),
                         n_steps=c["n_steps"] + 1, **upd)
 
+        @scoped("sched.step")
         def step(c):
             if self.obs is None:
                 return step_core(c)
@@ -899,8 +918,18 @@ class ContinuousBatchingScheduler:
 
     def loop_hlo_text(self, n_queue: int) -> str:
         """Pre-optimization StableHLO of the serve loop (fingerprint
-        input for the zero-overhead-when-off gate, obs/fingerprint.py)."""
+        input for the zero-overhead-when-off gate, obs/fingerprint.py).
+        It carries no named scopes: those live in debug locations."""
         return self._lower_loop(n_queue).as_text()
+
+    def compiled_loop_text(self, n_queue: int) -> str:
+        """Optimised HLO text of the compiled serve loop for a queue of
+        ``n_queue`` (compiled first if need be).  Every instruction's
+        ``metadata={op_name="..."}`` holds its JAX name stack, the
+        ``sched.*`` branch and ``lm.*`` layer scopes included, so a
+        profiler trace's device ops can be put down to them by
+        instruction name."""
+        return self.compile_for(n_queue).as_text()
 
     # -- segmented (guarded) serve loop --------------------------------
 
@@ -1089,9 +1118,10 @@ class ContinuousBatchingScheduler:
             lay, C = self.paged, self.prefill_chunk
             enable = (self.prefix_sharing
                       and self.cfg.family not in ("ssm", "hybrid"))
-            plan = plan_prefix_sharing(
-                [np.asarray(r.prompt) for r in requests],
-                lay.block_size, lay.n_tbl, enable=enable)
+            with span("sched.plan_prefix"):
+                plan = plan_prefix_sharing(
+                    [np.asarray(r.prompt) for r in requests],
+                    lay.block_size, lay.n_tbl, enable=enable)
             self.last_prefix_plan = plan
             pins = plan.pin_counts.astype(np.int64)
             max_blks = np.zeros(n, np.int64)
@@ -1126,13 +1156,18 @@ class ContinuousBatchingScheduler:
         """Pre-compile the serve loop for a queue length (off the clock);
         ``lockstep=True`` also pre-compiles the baseline executables so a
         timed run_lockstep never pays compile, ``instrumented=True`` the
-        single-iteration executable run_instrumented steps."""
+        single-iteration executable run_instrumented steps.  Each build
+        is a ``launch.compile`` span (a load, with a warm persistent
+        compile cache)."""
         if n_requests not in self._loops:
-            self._loops[n_requests] = self._build_loop(n_requests)
-        if lockstep:
-            self._lockstep_executables()
+            with span("launch.compile", what="loop", q=n_requests):
+                self._loops[n_requests] = self._build_loop(n_requests)
+        if lockstep and self._lockstep_exes is None:
+            with span("launch.compile", what="lockstep"):
+                self._lockstep_executables()
         if instrumented and n_requests not in self._iters:
-            self._iters[n_requests] = self._build_iter(n_requests)
+            with span("launch.compile", what="iter", q=n_requests):
+                self._iters[n_requests] = self._build_iter(n_requests)
         return self._loops[n_requests]
 
     def run(self, requests: Sequence[Request],
@@ -1140,19 +1175,46 @@ class ContinuousBatchingScheduler:
         """Serve ``requests`` to completion.  ``arrival_iters`` holds an
         open-loop arrival schedule in LOOP-ITERATION units (the device
         clock): request i is not admitted before iteration
-        arrival_iters[i].  Default: everything arrives at t=0."""
-        self._check(requests)
+        arrival_iters[i].  Default: everything arrives at t=0.
+
+        The call is host span ``sched.run`` (attrs: the scheduler's batch
+        serial number and Q) over ``sched.check``, ``sched.stage``,
+        ``sched.carry``, ``sched.loop`` (dispatch to block_until_ready)
+        and ``sched.fetch`` (results to host, report built); their
+        seconds land in ``ServeReport.host_s`` and the compiles the call
+        made in ``n_compiles``."""
+        self._n_runs += 1
+        compiles0 = backend_compiles()
+        with get_tracer().collect() as spans:
+            with span("sched.run", batch=self._n_runs, q=len(requests)):
+                report = self._run(requests, arrival_iters)
+        for s in spans:
+            report.host_s[s["name"]] = (report.host_s.get(s["name"], 0.0)
+                                        + s["dur_s"])
+        report.n_compiles = backend_compiles() - compiles0
+        return report
+
+    def _run(self, requests, arrival_iters) -> ServeReport:
+        with span("sched.check"):
+            self._check(requests)
         loop = self.compile_for(len(requests))
-        q_toks, q_meta, q_pins = self._stage(requests, arrival_iters)
-        carry = jax.block_until_ready(
-            self._init_carry(len(requests), with_obs=False))
-        args = (q_toks, q_meta, q_pins)
-        if self.obs is not None:
-            args = (jax.block_until_ready(
-                obs_rings.init_obs_state(self.obs)),) + args
-        t0 = time.time()                    # compile + staging off the clock
-        res = jax.block_until_ready(loop(self._params, carry, *args))
-        wall = time.time() - t0
+        with span("sched.stage"):
+            q_toks, q_meta, q_pins = self._stage(requests, arrival_iters)
+        with span("sched.carry"):
+            carry = jax.block_until_ready(
+                self._init_carry(len(requests), with_obs=False))
+            args = (q_toks, q_meta, q_pins)
+            if self.obs is not None:
+                args = (jax.block_until_ready(
+                    obs_rings.init_obs_state(self.obs)),) + args
+        with span("sched.loop"):
+            t0 = time.time()                # compile + staging off the clock
+            res = jax.block_until_ready(loop(self._params, carry, *args))
+            wall = time.time() - t0
+        with span("sched.fetch"):
+            return self._report(requests, res, wall)
+
+    def _report(self, requests, res, wall: float) -> ServeReport:
         res_out, res_n = np.asarray(res["res_out"]), np.asarray(res["res_n"])
         res_iter, n_iter = np.asarray(res["res_iter"]), int(res["n_iter"])
         res_first = np.asarray(res["res_first"])
@@ -1167,7 +1229,8 @@ class ContinuousBatchingScheduler:
                              n_drafted=int(res["n_drafted"]),
                              n_accepted=int(res["n_accepted"]),
                              n_pf=int(res.get("n_pf", 0)),
-                             peak_blocks=int(res.get("peak_blocks", 0)))
+                             peak_blocks=int(res.get("peak_blocks", 0)),
+                             n_iter=n_iter)
         if self.obs is not None:
             report.obs = obs_rings.harvest_obs(
                 self.obs, jax.device_get(res["obs"]), n_iter=n_iter,
@@ -1224,7 +1287,7 @@ class ContinuousBatchingScheduler:
             n_admits=int(c["n_admits"]), slots=self.slots,
             n_drafted=int(c["n_drafted"]), n_accepted=int(c["n_accepted"]),
             n_pf=int(c.get("n_pf", 0)),
-            peak_blocks=int(c.get("peak_blocks", 0)))
+            peak_blocks=int(c.get("peak_blocks", 0)), n_iter=len(branches))
         if self.obs is not None:
             # the instrumented runner's carry keeps the rings inline
             # (the host round-trips it), so harvest reads them directly

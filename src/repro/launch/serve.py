@@ -72,6 +72,8 @@ def serve(arch: str, smoke: bool = True, batch: int = 4, prompt_len: int = 32,
     mamba-input projections execute as one wide macro GEMM each, tokens
     bit-identical to the unfused path (``fuse=False`` is the A/B baseline).
     """
+    if metrics:
+        get_tracer().record = True  # stats["spans"]: this call's spans
     cfg = get_config(arch, smoke=smoke)
     if plan is not None:
         cim = True
@@ -192,6 +194,7 @@ def serve(arch: str, smoke: bool = True, batch: int = 4, prompt_len: int = 32,
             [t_decode / decode_steps] * decode_steps if decode_steps else [])
         stats["metrics"] = REGISTRY.snapshot()
         stats["spans"] = get_tracer().drain()
+        get_tracer().record = False
     mode = ("cim-packed" if pack else "cim-unpacked") if cim else "fp"
     print(f"[serve] {arch} ({mode}): batch {batch}, prompt {prompt_len}, "
           f"gen {gen} | compile {t_compile:.2f}s, pack {t_pack:.2f}s, "
@@ -234,6 +237,8 @@ def serve_speculative(arch: str, smoke: bool = True, batch: int = 2,
     stats) including acceptance_rate, tokens_per_round and (when
     ``compare_baseline``) ``decode_speedup_speculative``.
     """
+    if metrics:
+        get_tracer().record = True  # stats["spans"]: this call's spans
     cfg = get_config(arch, smoke=smoke)
     if plan is not None:
         cim = True
@@ -389,6 +394,7 @@ def serve_speculative(arch: str, smoke: bool = True, batch: int = 2,
                        "lock-step decode throughput").set(decode_tok_s)
         stats["metrics"] = REGISTRY.snapshot()
         stats["spans"] = get_tracer().drain()
+        get_tracer().record = False
     print(f"[serve-spec] {arch} (k={K}, draft {stats['draft_plan']}): "
           f"batch {batch}, gen {gen} | decode {t_decode:.2f}s "
           f"({decode_tok_s:.1f} tok/s), acceptance "
@@ -458,6 +464,8 @@ def serve_continuous(arch: str, smoke: bool = True, slots: int = 2,
     """
     obs = (metrics if isinstance(metrics, ObsConfig)
            else (ObsConfig() if metrics else None))
+    if obs is not None:
+        get_tracer().record = True  # stats["spans"]: this call's spans
     if draft_k and temperature > 0:
         compare_lockstep = False
     compare_contiguous = paged is not None and compare_lockstep
@@ -566,6 +574,7 @@ def serve_continuous(arch: str, smoke: bool = True, slots: int = 2,
         stats["telemetry"] = report.obs.to_dict()
         stats["metrics"] = REGISTRY.snapshot()
         stats["spans"] = get_tracer().drain()
+        get_tracer().record = False
     mode = ("cim-packed" if pack else "cim-unpacked") if cim else "fp"
     if draft_k:
         mode += f"+spec-k{draft_k}"

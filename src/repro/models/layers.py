@@ -27,6 +27,7 @@ from ..core.ccim import DEFAULT_CONFIG
 from ..core.engine import (CimEngine, FusedPackedCimWeights,
                            PackedCimWeights)
 from ..kernels.paged_attn import ops as paged_attn_ops
+from ..obs.scopes import scoped
 from .config import ModelConfig
 
 Array = jax.Array
@@ -72,6 +73,7 @@ def _dense_noise_key(cfg: ModelConfig, path: Optional[str]) -> Optional[Array]:
     return jax.random.fold_in(jax.random.PRNGKey(cfg.cim_noise_seed), tag)
 
 
+@scoped("lm.cim")
 def _dense(x: Array, w, cfg: ModelConfig, path: Optional[str] = None) -> Array:
     """x (..., K) @ w (K, N) -- through the macro when cim_mode is on.
 
@@ -169,6 +171,7 @@ def _dense_fused(x: Array, leaf: FusedPackedCimWeights, cfg: ModelConfig,
     return _split_segments(y, names, leaf.seg_dims)
 
 
+@scoped("lm.cim")
 def _dense_group(x: Array, p: Params, names, cfg: ModelConfig,
                  prefix: str) -> Dict[str, Array]:
     """Run a block's projections that all consume ``x``, fusing plan-
@@ -547,6 +550,20 @@ def attention_apply(
     """
     B, S, _ = x.shape
     q, k, v = _qkv(p, x, cfg, positions, path)
+    out, new_kv = _attention_core(q, k, v, cfg, positions, is_local,
+                                  kv_cache, cache_pos, n_prefix, return_kv,
+                                  block_table, write_mask)
+    return _attn_out(p, out, cfg, B, S, path), new_kv
+
+
+@scoped("lm.attn")
+def _attention_core(q, k, v, cfg: ModelConfig, positions, is_local,
+                    kv_cache, cache_pos, n_prefix, return_kv, block_table,
+                    write_mask):
+    """``attention_apply`` between its projections: the cache write
+    (contiguous rows or paged scatter), the read, and the attention
+    itself.  Returns (out (B,S,H,Dh), new_kv or None)."""
+    B, S = q.shape[:2]
     new_kv = None
     if kv_cache is not None and block_table is not None:
         ck, cv = kv_cache
@@ -568,7 +585,7 @@ def attention_apply(
                 q[:, 0], new_kv[0], new_kv[1], block_table, cache_pos + 1,
                 is_local, softcap=cfg.attn_softcap,
                 window=cfg.sliding_window)[:, None]
-            return _attn_out(p, out, cfg, B, S, path), new_kv
+            return out, new_kv
         L = n_tbl * bs
         idx = (block_table[:, :, None] * bs
                + jnp.arange(bs, dtype=jnp.int32)[None, None, :]).reshape(B, L)
@@ -602,7 +619,7 @@ def attention_apply(
     else:
         out = plain_attention(q, k_full, v_full, positions, k_pos, cfg,
                               is_local, n_prefix)
-    return _attn_out(p, out, cfg, B, S, path), new_kv
+    return out, new_kv
 
 
 def _attn_out(p: Params, out: Array, cfg: ModelConfig, B: int, S: int,
@@ -832,9 +849,24 @@ def _causal_conv(u, w, b, state=None):
 def mamba2_apply(p: Params, x: Array, cfg: ModelConfig,
                  ssm_state=None, conv_state=None, decode: bool = False):
     """x (B,S,D). Returns (y, (new_ssm_state, new_conv_state))."""
+    proj = _dense_group(x, p, ("w_z", "w_x", "w_bc", "w_dt"), cfg, "mamba/")
+    y, new_state, new_conv = _mamba2_mixer(p, proj, x, cfg, ssm_state,
+                                           conv_state, decode)
+    out = _dense(y, p["out_proj"], cfg, "mamba/out_proj")
+    if out.shape[1] != x.shape[1]:
+        out = out[:, :x.shape[1]]
+    return out, (new_state, new_conv)
+
+
+@scoped("lm.ssm")
+def _mamba2_mixer(p: Params, proj, x: Array, cfg: ModelConfig, ssm_state,
+                  conv_state, decode: bool):
+    """mamba2 between its projections: the causal convs, the chunked SSD
+    scan (or the single-step recurrence), the state update and the gate.
+    Returns (y (B, S padded to a chunk multiple, DI), new_ssm_state,
+    new_conv_state)."""
     B, S, D = x.shape
     DI, N, H, P = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads, cfg.ssm_head_dim
-    proj = _dense_group(x, p, ("w_z", "w_x", "w_bc", "w_dt"), cfg, "mamba/")
     z, xc, BCc, dt_raw = (proj[n] for n in ("w_z", "w_x", "w_bc", "w_dt"))
     cs_x = cs_bc = None
     if conv_state is not None:
@@ -847,8 +879,8 @@ def mamba2_apply(p: Params, x: Array, cfg: ModelConfig,
     A = -jnp.exp(p["A_log"])                                          # (H,)
 
     # pad S to a chunk multiple; padded steps get dt=0 => identity decay,
-    # zero state update, so the recurrence is unaffected
-    S_orig = S
+    # zero state update, so the recurrence is unaffected (mamba2_apply
+    # slices the padding off after the output projection)
     if not decode:
         Q0 = min(cfg.ssm_chunk, S)
         pad = (Q0 - S % Q0) % Q0
@@ -915,7 +947,4 @@ def mamba2_apply(p: Params, x: Array, cfg: ModelConfig,
 
     y = y.astype(x.dtype) * jax.nn.silu(z)
     y = rms_norm(y, p["gate_norm"], cfg.norm_eps)
-    out = _dense(y, p["out_proj"], cfg, "mamba/out_proj")
-    if not decode and S != S_orig:
-        out = out[:, :S_orig]
-    return out, (new_state, new_conv)
+    return y, new_state, new_conv

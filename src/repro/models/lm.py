@@ -39,6 +39,8 @@ import jax.numpy as jnp
 
 from ..core.engine import FusedPackedCimWeights
 from ..obs import taps
+from ..obs.scopes import scoped
+from ..obs.trace import span
 from . import layers as L
 from .config import ModelConfig
 
@@ -274,10 +276,14 @@ def pack_cim_params(params: Params, cfg: ModelConfig) -> Params:
     -- the decode hot path then runs ~3 wide GEMMs per block instead of ~7
     skinny ones, with per-projection outputs (and noise streams) still
     bit-identical to the unfused pack (see DESIGN.md section 9).
+
+    The call is host span ``launch.pack`` and returns once the pack is
+    on the device (under an outer jit it only traces).
     """
     if not cfg.cim_mode:
         raise ValueError("pack_cim_params requires cfg.cim_mode=True")
-    return _pack_cim_params_jit(params, cfg)
+    with span("launch.pack"):
+        return jax.block_until_ready(_pack_cim_params_jit(params, cfg))
 
 
 # ---------------------------------------------------------------------------
@@ -290,6 +296,7 @@ def _is_local_arr(cfg: ModelConfig) -> Array:
         [cfg.layer_is_local(i) for i in range(cfg.n_layers)], jnp.bool_)
 
 
+@scoped("lm.embed")
 def _embed(p, cfg: ModelConfig, tokens: Array,
            frontend_embs: Optional[Array]) -> Tuple[Array, int]:
     x = jnp.take(p["embed"], tokens, axis=0)
@@ -300,6 +307,7 @@ def _embed(p, cfg: ModelConfig, tokens: Array,
     return x, n_prefix
 
 
+@scoped("lm.head")
 def _logits(p, cfg: ModelConfig, x: Array) -> Array:
     x = L.rms_norm(x, p["ln_f"], cfg.norm_eps)
     head = p["embed"].T if cfg.tie_embeddings else p["lm_head"]
@@ -549,6 +557,7 @@ def reset_slot(cache: Dict, slot) -> Dict:
     return slot_update(cache, _zeroed_slot(cache, slot), slot)
 
 
+@scoped("lm.prefill")
 def prefill_into_slot(params, cfg: ModelConfig, tokens: Array, cache: Dict,
                       slot, frontend_embs: Optional[Array] = None
                       ) -> Tuple[Array, Dict]:
@@ -565,6 +574,7 @@ def prefill_into_slot(params, cfg: ModelConfig, tokens: Array, cache: Dict,
     return logits, slot_update(cache, sub, slot)
 
 
+@scoped("lm.prefill")
 def prefill_chunk_into_slot(params, cfg: ModelConfig, tokens: Array,
                             cache: Dict, slot) -> Tuple[Array, Dict]:
     """Advance ONE slot's prefill by one chunk (tokens (1, C)).
@@ -648,6 +658,7 @@ def prefill(params, cfg: ModelConfig, tokens: Array, cache: Dict,
     return logits, cache
 
 
+@scoped("lm.step")
 def decode_step(params, cfg: ModelConfig, token: Array, cache: Dict,
                 live: Optional[Array] = None) -> Tuple[Array, Dict]:
     """token (B, 1) -> logits (B, 1, V); cache advanced by one position.
@@ -663,7 +674,8 @@ def decode_step(params, cfg: ModelConfig, token: Array, cache: Dict,
     is held (a filling slot's recurrence must survive interleaved pool
     steps until its next chunk).
     """
-    x = jnp.take(params["embed"], token, axis=0)
+    with jax.named_scope("lm.embed"):
+        x = jnp.take(params["embed"], token, axis=0)
     pos = cache["pos"]
     positions = pos[:, None].astype(jnp.int32)
     cache = dict(cache)
@@ -695,6 +707,7 @@ def decode_step(params, cfg: ModelConfig, token: Array, cache: Dict,
     return _logits(params, cfg, x), cache
 
 
+@scoped("lm.verify")
 def verify_step(params, cfg: ModelConfig, tokens: Array, cache: Dict,
                 live: Optional[Array] = None) -> Tuple[Array, Dict]:
     """tokens (B, S) -> logits (B, S, V): the speculative-verify forward.
@@ -727,7 +740,8 @@ def verify_step(params, cfg: ModelConfig, tokens: Array, cache: Dict,
             "a draft block cannot roll back")
     if cfg.attn_impl != "plain":
         cfg = dataclasses.replace(cfg, attn_impl="plain")
-    x = jnp.take(params["embed"], tokens, axis=0)
+    with jax.named_scope("lm.embed"):
+        x = jnp.take(params["embed"], tokens, axis=0)
     S = tokens.shape[1]
     pos = cache["pos"]
     positions = pos[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]

@@ -6,8 +6,9 @@ arrays written with masked scatter updates, so the loop still syncs the
 host exactly once per workload.  The host half turns the harvested
 rings into typed spans / histograms (`rings.harvest_obs`), maintains a
 pull-style metrics registry with Prometheus-text and JSON exporters
-(`metrics`), and emits structured JSON-lines span traces for the
-serving drivers (`trace`).
+(`metrics`), emits structured JSON-lines span traces for the serving
+drivers (`trace`), and counts the process's XLA backend compiles
+(`compiles`).
 
 Telemetry is a STATIC flag: a scheduler built with ``obs=None``
 compiles to an executable byte-identical to the pre-telemetry one
@@ -15,6 +16,7 @@ compiles to an executable byte-identical to the pre-telemetry one
 a metrics-on scheduler emits bit-identical tokens -- rings only ever
 read values the loop already computes.
 """
+from .compiles import backend_compiles
 from .fingerprint import hlo_fingerprint, scheduler_fingerprint
 from .hostinfo import BENCH_SCHEMA_VERSION, host_fingerprint, host_matches
 from .metrics import REGISTRY, MetricsRegistry
@@ -27,6 +29,7 @@ __all__ = [
     "EV_ADMIT", "EV_FIRST", "EV_FINISH",
     "MetricsRegistry", "REGISTRY",
     "SpanTracer", "get_tracer", "set_trace_path", "span",
+    "backend_compiles",
     "host_fingerprint", "host_matches", "BENCH_SCHEMA_VERSION",
     "hlo_fingerprint", "scheduler_fingerprint",
 ]

@@ -15,13 +15,20 @@ The telemetry contract has three load-bearing clauses, each pinned here:
    executable (cross-commit byte-identity of the off program is gated in
    benchmarks/serve_bench.py --check-regression).
 
-Plus the host half: the Prometheus exposition must parse.
+Plus the host half: the Prometheus exposition must parse; spans are
+kept only while recording and land on the profiler's host plane on the
+clock of their JSONL ``t0``; the compiled loop names the program's
+scopes, which leave the fingerprinted lowering untouched; the compile
+counter sees a run that compiles.
 """
+import contextlib
 import dataclasses
 import json
 import re
+import time
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -30,7 +37,8 @@ from repro.launch.paging import PagedLayout
 from repro.launch.scheduler import (ContinuousBatchingScheduler,
                                     mixed_length_requests)
 from repro.models import lm
-from repro.obs import MetricsRegistry, ObsConfig, scheduler_fingerprint
+from repro.obs import (MetricsRegistry, ObsConfig, SpanTracer,
+                       scheduler_fingerprint)
 from repro.obs import rings as R
 
 P, CAP = 8, 4
@@ -261,3 +269,161 @@ def test_harvest_fully_dropped_event_ring():
     reg = MetricsRegistry()
     snap.register(reg)
     assert "NaN" not in reg.export_prometheus()
+
+
+# ---------------------------------------------------------------------------
+# 8. host spans: recording, the profiler's clock
+# ---------------------------------------------------------------------------
+
+
+def test_spans_kept_only_while_recording(tmp_path):
+    tr = SpanTracer()
+    for i in range(10_000):
+        with tr.span("test.off", i=i):
+            pass
+    assert not tr.recording and tr.spans == []
+    with tr.collect() as got:                 # handed over, not kept
+        with tr.span("test.collected"):
+            pass
+    assert [s["name"] for s in got] == ["test.collected"] and tr.spans == []
+    tr.record = True
+    with tr.span("test.on", n=1) as attrs:
+        attrs["m"] = 2
+    assert [(s["name"], s["attrs"]) for s in tr.drain()] == [
+        ("test.on", {"n": 1, "m": 2})]
+    path = tmp_path / "spans.jsonl"
+    to_file = SpanTracer(path=str(path))
+    with to_file.span("test.file"):
+        pass
+    assert json.loads(path.read_text())["name"] == "test.file"
+    assert len(to_file.spans) == 1
+
+
+def test_span_lands_on_the_profiler_host_plane(tmp_path):
+    from jax.profiler import ProfileData
+    tr = SpanTracer(path=str(tmp_path / "spans.jsonl"))
+    jax.profiler.start_trace(str(tmp_path / "prof"))
+    try:
+        with tr.span("test.probe"):
+            time.sleep(0.005)
+    finally:
+        jax.profiler.stop_trace()
+    rec = json.loads((tmp_path / "spans.jsonl").read_text())
+    pd = ProfileData.from_file(str(next(tmp_path.glob("prof/**/*.xplane.pb"))))
+    # host event starts are relative to the session's profile_start_time
+    env = {k: v for p in pd.planes if p.name == "Task Environment"
+           for k, v in dict(p.stats).items()}
+    hits = [e for p in pd.planes if p.name.startswith("/host:")
+            for line in p.lines for e in line.events if e.name == "test.probe"]
+    assert len(hits) == 1
+    start = (env["profile_start_time"] + hits[0].start_ns) * 1e-9
+    assert abs(start - rec["t0"]) < 1e-3
+    assert abs(hits[0].duration_ns * 1e-9 - rec["dur_s"]) < 1e-3
+
+
+# ---------------------------------------------------------------------------
+# 9. device scopes in the compiled loop; the compile counter
+# ---------------------------------------------------------------------------
+
+SCOPES = ("sched.harvest", "sched.admit", "sched.prefill", "sched.step",
+          "sched.sample", "lm.step", "lm.prefill", "lm.embed", "lm.cim",
+          "lm.head")
+
+
+@pytest.fixture(scope="module")
+def packed_ssm():
+    cfg = dataclasses.replace(get_config("mamba2-130m", smoke=True),
+                              cim_mode=True)
+    params, _ = lm.init(jax.random.PRNGKey(0), cfg)
+    return jax.jit(lambda p: lm.pack_cim_params(p, cfg))(params), cfg
+
+
+def _paged_sched(params, cfg):
+    return ContinuousBatchingScheduler(
+        params, cfg, slots=2, prompt_len=16, max_new_cap=CAP,
+        paged=PagedLayout(block_size=8, n_tbl=3, n_blocks=12),
+        prefill_chunk=8)
+
+
+@pytest.mark.parametrize("family", ["dense", "ssm"])
+def test_compiled_loop_names_every_scope(packed_cim, packed_ssm, family,
+                                         monkeypatch):
+    params, cfg = packed_cim if family == "dense" else packed_ssm
+    layer = "lm.attn" if family == "dense" else "lm.ssm"
+    sched = _paged_sched(params, cfg)
+    text = sched.compiled_loop_text(2)
+    stacks = {c for n in re.findall(r'op_name="([^"]*)"', text)
+              for c in n.split("/")}
+    assert set(SCOPES) | {layer} <= stacks
+    lowered = sched.loop_hlo_text(2)
+    assert not any(s in lowered for s in SCOPES + (layer,))
+    # without the scopes: the same lowering byte for byte, and a compiled
+    # loop that names none of them
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare = _paged_sched(params, cfg)
+    assert bare.loop_hlo_text(2) == lowered
+    assert not any(s in bare.compiled_loop_text(2) for s in SCOPES)
+
+
+@pytest.fixture
+def no_persistent_cache():
+    """Every compile a backend compile: nothing loads from disk."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def test_report_counts_compiles_iterations_and_host_spans(
+        packed_cim, no_persistent_cache):
+    params, cfg = packed_cim
+    sched = ContinuousBatchingScheduler(params, cfg, slots=2, prompt_len=P,
+                                        max_new_cap=CAP)
+    reqs = _requests(cfg)
+    first = sched.run(reqs)
+    assert first.n_compiles > 0
+    again = sched.run(reqs)
+    assert again.n_compiles == 0
+    # every request is harvested once; the other iterations are counted
+    assert again.n_iter == (again.n_steps + again.n_admits + again.n_pf
+                            + len(reqs))
+    assert set(again.host_s) == {"sched.run", "sched.check", "sched.stage",
+                                 "sched.carry", "sched.loop", "sched.fetch"}
+    assert "launch.compile" in first.host_s
+    assert again.host_s["sched.loop"] <= again.host_s["sched.run"]
+    assert sched.run(reqs[:3]).n_compiles > 0          # a new queue length
+
+
+def test_compile_counter_does_not_count_cache_loads(tmp_path):
+    """An executable served from the persistent compile cache is no
+    compile; the same program built anew is one."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from repro.obs import backend_compiles, compiles
+    keep = {k: getattr(jax.config, k) for k in (
+        "jax_enable_compilation_cache", "jax_compilation_cache_dir",
+        "jax_persistent_cache_min_compile_time_secs",
+        "jax_persistent_cache_min_entry_size_bytes")}
+    jax.config.update("jax_enable_compilation_cache", True)
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    cc.reset_cache()
+    try:
+        f = lambda x: jnp.sin(x) * 3.0 + 0.25
+        x = jnp.arange(7.0)
+        n0, built0 = backend_compiles(), compiles._builds
+        jax.jit(f).lower(x).compile()
+        assert backend_compiles() == n0 + 1
+        jax.clear_caches()                    # built again: from the disk
+        jax.jit(f).lower(x).compile()
+        assert compiles._builds == built0 + 2
+        assert backend_compiles() == n0 + 1
+    finally:
+        for k, v in keep.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
