@@ -45,6 +45,9 @@ def paged_attention_decode(
     use_pallas: Optional[bool] = None,
     interpret: Optional[bool] = None,
 ) -> jax.Array:
+    """A row of length 0 is one the step does not decode: the kernel
+    walks none of its blocks and returns zeros for it (the fallback
+    returns an unmasked average); either way its output is unused."""
     on_tpu = jax.default_backend() == "tpu"
     if use_pallas is None:
         use_pallas = kernel_enabled()

@@ -125,6 +125,7 @@ class FinishedRequest:
     finish_iter: int              # loop iteration the request finished at
     first_iter: int = 0           # loop iteration its first token appeared
     ttft_s: float = float("nan")  # measured only by run_instrumented
+    prompt_len: int = 0           # prompt tokens
 
 
 @dataclasses.dataclass
@@ -143,6 +144,9 @@ class ServeReport:
     n_compiles: int = 0           # XLA backend compiles during run()
     # host seconds of each span closed inside run() (sched.*, launch.*)
     host_s: Dict[str, float] = dataclasses.field(default_factory=dict)
+    # paged decode-attention geometry; 0 when unpaged or attention-free
+    block_size: int = 0
+    n_tbl: int = 0
 
     @property
     def total_tokens(self) -> int:
@@ -171,6 +175,21 @@ class ServeReport:
         yield one token; every pooled step spends ``slots`` slot-steps)."""
         slot_steps = self.slots * self.n_steps + self.n_admits + self.n_pf
         return self.total_tokens / slot_steps if slot_steps else float("nan")
+
+    @property
+    def attn_live_block_frac(self) -> float:
+        """Share of the paged decode-attention grid (slots x table
+        entries, every pooled step) that live rows' lengths cover: each
+        served token after the first is one decode row at context
+        ``prompt_len + i``, which needs ceil((prompt_len + i) /
+        block_size) blocks.  0 when unpaged or attention-free."""
+        grid = self.n_steps * self.slots * self.n_tbl
+        if not grid:
+            return 0.0
+        bs = self.block_size
+        live = sum(-(-(f.prompt_len + i) // bs)
+                   for f in self.finished for i in range(1, len(f.tokens)))
+        return live / grid
 
     def latency_percentiles(self) -> Dict[str, float]:
         lats = sorted(f.latency_s for f in self.finished)
@@ -1214,6 +1233,12 @@ class ContinuousBatchingScheduler:
         with span("sched.fetch"):
             return self._report(requests, res, wall)
 
+    def _attn_geometry(self) -> Dict[str, int]:
+        """The report's paged decode-attention geometry, if any."""
+        if self.paged is None or self.cfg.family == "ssm":
+            return {}
+        return dict(block_size=self.paged.block_size, n_tbl=self.paged.n_tbl)
+
     def _report(self, requests, res, wall: float) -> ServeReport:
         res_out, res_n = np.asarray(res["res_out"]), np.asarray(res["res_n"])
         res_iter, n_iter = np.asarray(res["res_iter"]), int(res["n_iter"])
@@ -1221,7 +1246,8 @@ class ContinuousBatchingScheduler:
         done = [FinishedRequest(
             rid=r.rid, tokens=res_out[i, :res_n[i]].copy(),
             latency_s=wall * int(res_iter[i]) / max(n_iter, 1),
-            finish_iter=int(res_iter[i]), first_iter=int(res_first[i]))
+            finish_iter=int(res_iter[i]), first_iter=int(res_first[i]),
+            prompt_len=len(r.prompt))
             for i, r in enumerate(requests)]
         report = ServeReport(finished=done, wall_s=wall,
                              n_steps=int(res["n_steps"]),
@@ -1230,7 +1256,7 @@ class ContinuousBatchingScheduler:
                              n_accepted=int(res["n_accepted"]),
                              n_pf=int(res.get("n_pf", 0)),
                              peak_blocks=int(res.get("peak_blocks", 0)),
-                             n_iter=n_iter)
+                             n_iter=n_iter, **self._attn_geometry())
         if self.obs is not None:
             report.obs = obs_rings.harvest_obs(
                 self.obs, jax.device_get(res["obs"]), n_iter=n_iter,
@@ -1280,14 +1306,16 @@ class ContinuousBatchingScheduler:
         done = [FinishedRequest(
             rid=r.rid, tokens=res_out[i, :res_n[i]].copy(),
             latency_s=at(res_iter[i]), finish_iter=int(res_iter[i]),
-            first_iter=int(res_first[i]), ttft_s=at(res_first[i]))
+            first_iter=int(res_first[i]), ttft_s=at(res_first[i]),
+            prompt_len=len(r.prompt))
             for i, r in enumerate(requests)]
         report = ServeReport(
             finished=done, wall_s=wall, n_steps=int(c["n_steps"]),
             n_admits=int(c["n_admits"]), slots=self.slots,
             n_drafted=int(c["n_drafted"]), n_accepted=int(c["n_accepted"]),
             n_pf=int(c.get("n_pf", 0)),
-            peak_blocks=int(c.get("peak_blocks", 0)), n_iter=len(branches))
+            peak_blocks=int(c.get("peak_blocks", 0)), n_iter=len(branches),
+            **self._attn_geometry())
         if self.obs is not None:
             # the instrumented runner's carry keeps the rings inline
             # (the host round-trips it), so harvest reads them directly

@@ -581,8 +581,12 @@ def _attention_core(q, k, v, cfg: ModelConfig, positions, is_local,
             v.astype(cv.dtype).reshape(B * S, hkv, dh))
         new_kv = (ckf.reshape(ck.shape), cvf.reshape(cv.shape))
         if S == 1 and n_prefix == 0 and paged_attn_ops.kernel_enabled():
+            # length 0: a row this step does not decode walks no blocks
+            lens = cache_pos + 1
+            if write_mask is not None:
+                lens = jnp.where(write_mask, lens, 0)
             out = paged_attn_ops.paged_attention_decode(
-                q[:, 0], new_kv[0], new_kv[1], block_table, cache_pos + 1,
+                q[:, 0], new_kv[0], new_kv[1], block_table, lens,
                 is_local, softcap=cfg.attn_softcap,
                 window=cfg.sliding_window)[:, None]
             return out, new_kv

@@ -21,7 +21,8 @@ except ImportError:                                   # pragma: no cover
 from repro.configs import get_config
 from repro.launch.paging import (BlockAllocator, PagedLayout,
                                  contiguous_kv_bytes, plan_prefix_sharing)
-from repro.launch.scheduler import ContinuousBatchingScheduler, Request
+from repro.launch.scheduler import (ContinuousBatchingScheduler,
+                                    FinishedRequest, Request, ServeReport)
 from repro.models import lm
 
 
@@ -250,11 +251,33 @@ def test_paged_recurrent_families_bit_exact(arch):
               seed=5)
     want = ContinuousBatchingScheduler(params, cfg, **kw).run(
         reqs).tokens_by_rid()
-    got = ContinuousBatchingScheduler(
+    rep = ContinuousBatchingScheduler(
         params, cfg, paged=PagedLayout(4, 10, 40), prefill_chunk=8,
-        **kw).run(reqs).tokens_by_rid()
+        **kw).run(reqs)
+    got = rep.tokens_by_rid()
     for rid in want:
         np.testing.assert_array_equal(got[rid], want[rid])
+    # only zamba2's shared attention block reads the paged pool
+    assert (rep.attn_live_block_frac > 0) == (arch == "zamba2-1.2b")
+
+
+def test_paged_decode_kernel_tokens_match_fallback(monkeypatch):
+    """The Pallas decode read (interpret mode here), which skips the
+    blocks past each row's length and every block of a row the step does
+    not decode, serves the same greedy tokens as the XLA gather fallback
+    (its f32 PV differs from the fallback's bf16 PV in the last bits, so
+    sampled tokens may part)."""
+    params, cfg = _params("qwen3-14b")
+    reqs = _mixed_requests(cfg, 4, [16, 9, 13], [6, 8], seed=3)
+    kw = dict(slots=2, prompt_len=16, max_new_cap=8, seed=5,
+              prefill_chunk=8, paged=PagedLayout(4, 10, 40))
+    got = {}
+    for env in ("0", "1"):
+        monkeypatch.setenv("REPRO_PAGED_ATTN", env)
+        got[env] = ContinuousBatchingScheduler(
+            params, cfg, **kw).run(reqs).tokens_by_rid()
+    for rid in got["0"]:
+        np.testing.assert_array_equal(got["1"][rid], got["0"][rid])
 
 
 def test_block_recycling_and_refcount_drain():
@@ -272,6 +295,25 @@ def test_block_recycling_and_refcount_drain():
     # 12 requests x 6 blocks each = 72 grants > 47 allocatable blocks
     assert 0 < rep.peak_blocks <= lay.n_blocks - 1
     assert rep.n_admits == 12
+    # each request decodes at contexts 17..23: 5+5+5+5+6+6+6 = 38 blocks
+    assert (rep.block_size, rep.n_tbl) == (4, 12)
+    assert rep.attn_live_block_frac == 12 * 38 / (rep.n_steps * 2 * 12)
+
+
+def test_attn_live_block_frac_hand_counted():
+    """16-token blocks, 8-entry tables, 2 slots, 5 pooled steps.  A
+    (prompt 15, 4 tokens) decodes at contexts 16, 17, 18: 1 + 2 + 2
+    blocks; B (prompt 32, 3 tokens) at 33, 34: 3 + 3.  11 of 80."""
+    fin = [FinishedRequest(rid=0, tokens=np.zeros(4, np.int32),
+                           latency_s=0.0, finish_iter=0, prompt_len=15),
+           FinishedRequest(rid=1, tokens=np.zeros(3, np.int32),
+                           latency_s=0.0, finish_iter=0, prompt_len=32)]
+    rep = ServeReport(finished=fin, wall_s=1.0, n_steps=5, n_admits=2,
+                      slots=2, block_size=16, n_tbl=8)
+    assert rep.attn_live_block_frac == 11 / 80
+    unpaged = ServeReport(finished=fin, wall_s=1.0, n_steps=5, n_admits=2,
+                          slots=2)
+    assert unpaged.attn_live_block_frac == 0.0
 
 
 def test_arrival_schedule_and_instrumented_runner_token_invariance():

@@ -78,14 +78,19 @@ def test_general_prepacked_minicpm_qkv(one_chip, no_persistent_cache):
 
 
 def test_paged_attention_minicpm(one_chip, no_persistent_cache):
-    """Paged decode read at minicpm-2b widths: 48 (padded) MHA heads of
-    64, 16-token blocks."""
-    B, H, Dh, bs, n_blocks, n_tbl = 4, 48, 64, 16, 64, 12
-    fn = lambda q, k, v, t, n: paged_attention_pallas(q, k, v, t, n, False)
+    """Paged decode read as the minicpm-2b cells run it: 48 (padded) MHA
+    heads of 64, 16-token blocks, 16 slots of 112 table entries over the
+    default pool, rows at mixed lengths (0: a slot the step does not
+    decode, up to the full table)."""
+    B, H, Dh, bs, n_tbl = 16, 48, 64, 16, 112
+    n_blocks = B * n_tbl + 1
+    lens = jnp.asarray([0, 1, 15, 16, 17, 1024, 1100, n_tbl * bs] * 2,
+                       jnp.int32)
+    fn = lambda q, k, v, t: paged_attention_pallas(q, k, v, t, lens, False)
     _compile(fn, one_chip, ((B, H, Dh), jnp.bfloat16),
              ((n_blocks, bs, H, Dh), jnp.bfloat16),
              ((n_blocks, bs, H, Dh), jnp.bfloat16),
-             ((B, n_tbl), jnp.int32), ((B,), jnp.int32))
+             ((B, n_tbl), jnp.int32))
 
 
 @pytest.mark.parametrize("M", [4, 128])
