@@ -76,13 +76,14 @@ def _gaps(ref, tokens):
     return (jnp.max(ref, axis=1) - got) / jnp.std(ref, axis=1)
 
 
-def logit_gaps(params, model: dict, cases, seq_len: int, n_cap: int,
+def logit_gaps(arch, params, model: dict, cases, seq_len: int, n_cap: int,
                act: str, controls: Sequence[str] = ()
                ) -> Dict[str, List[np.ndarray]]:
-    """Per case (prompt, served): the gap of each served token ("served")
-    against the reference with activations in ``act``, and for each
-    control, named by its activation dtype, the gap of the token it puts
-    first at the same position of the same sequence."""
+    """Per case (prompt, served) of a model of architecture ``arch`` (its
+    module): the gap of each served token ("served") against the
+    reference with activations in ``act``, and for each control, named by
+    its activation dtype, the gap of the token it puts first at the same
+    position of the same sequence."""
     items = reference.model_items(model)
     out: Dict[str, List[np.ndarray]] = {"served": []}
     for name in controls:
@@ -94,12 +95,12 @@ def logit_gaps(params, model: dict, cases, seq_len: int, n_cap: int,
         seq[plen:plen + n - 1] = served[:-1]
         pad = np.zeros(n_cap, np.int32)
         pad[:n] = served
-        ref = reference.logits(params, jnp.asarray(seq), plen - 1,
+        ref = reference.logits(params, jnp.asarray(seq), plen - 1, arch=arch,
                                model_items=items, n_out=n_cap, act=act)
         out["served"].append(np.asarray(_gaps(ref, jnp.asarray(pad)))[:n])
         for name in controls:
             low = reference.logits(params, jnp.asarray(seq), plen - 1,
-                                   model_items=items, n_out=n_cap,
+                                   arch=arch, model_items=items, n_out=n_cap,
                                    act=name)
             top = jnp.argmax(low, axis=1).astype(jnp.int32)
             out[name].append(np.asarray(_gaps(ref, top))[:n])

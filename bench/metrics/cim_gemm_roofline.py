@@ -2,10 +2,12 @@
 their required work needs on the chip over their device time in the
 trace.
 
-Work (work.py): 2*M*K*N int8 operations per call over the rows the
-model must compute (prompt tokens not in shared blocks, and every served
-token but the last); bytes: every CIM weight once per model pass (decode
-steps + prefill chunks), 1 B per activation in and 4 B per int32 out.
+Work (work.py): 2*M*K*N int8 operations per call of a whole pass
+(``arch.cim_sites``) over the rows the model must compute (prompt tokens
+not in shared blocks, and every served token but the last), times the
+share of them the call sees (1 but for routed experts); bytes: every CIM
+weight once per model pass (decode steps + prefill chunks), 1 B per
+activation in and 4 B per int32 out.
 Least time = max(ops / int8 peak, bytes / HBM bandwidth); which of the
 two binds is logged.  Kernel time is the device time of the ops named
 in KERNELS; the log line compares their count per model pass with the
@@ -37,11 +39,11 @@ def read(run):
             shared = 0 if b.shared_blocks is None else int(b.shared_blocks[i]) * bs
             rows += work.request_rows(len(p), len(got[rid]), shared)
         passes += b.report.n_steps + b.report.n_pf
-    ops = work.cim_ops(run.model, rows)
-    nbytes = work.cim_bytes(run.model, rows, passes)
+    ops = work.cim_ops(run.arch, run.model, rows)
+    nbytes = work.cim_bytes(run.arch, run.model, rows, passes)
     t_ops = ops / run.peaks["int8_ops_per_s"]
     t_mem = nbytes / run.peaks["hbm_bytes_per_s"]
-    sites = len(work.cim_sites(run.model)) * run.model["n_layers"]
+    sites = len(run.arch.cim_sites(run.model))
     print(f"cim_gemm_roofline: {count} kernel events over {passes} model "
           f"passes = {count / max(passes, 1):.3f} per pass (a pass makes "
           f"{sites} CIM calls); {secs:.6f} s; bound by "

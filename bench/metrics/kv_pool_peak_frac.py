@@ -7,7 +7,7 @@ MOVES = "output_tok_s"
 
 
 def read(run):
-    if run.model["family"] == "ssm":
+    if run.arch.kv_bytes_per_token(run.model) == 0:
         return None
     peak = max(b.report.peak_blocks for b in run.batches)
     return peak / (run.layout["n_blocks"] - 1)

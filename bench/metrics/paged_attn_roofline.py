@@ -15,7 +15,7 @@ KERNELS = ("paged_attention_pallas",)
 def read(run):
     if run.trace is None or run.window is None or run.peaks is None:
         return None
-    if run.model["family"] == "ssm":
+    if run.arch.kv_bytes_per_token(run.model) == 0:
         return None
     secs, count = trace_reduce.device_time(run.trace, KERNELS, run.window)
     if count == 0 or secs <= 0:
@@ -24,8 +24,9 @@ def read(run):
     for b in run.batches:
         got = b.report.tokens_by_rid()
         for rid, p, _ in b.requests:
-            nbytes += work.decode_kv_bytes(run.model, len(p), len(got[rid]))
-            ops += work.decode_attn_ops(run.model, len(p), len(got[rid]))
+            n = len(got[rid])
+            nbytes += work.decode_kv_bytes(run.arch, run.model, len(p), n)
+            ops += work.decode_attn_ops(run.arch, run.model, len(p), n)
     least = max(nbytes / run.peaks["hbm_bytes_per_s"],
                 ops / run.peaks["bf16_flops_per_s"])
     return 100.0 * least / secs

@@ -3,6 +3,7 @@
 controls' gaps on several seeds, in one process that holds the chip.
 
     python3 bench/readings.py --workload <cell> --seeds 11 12 13 [--batches 1]
+    python3 bench/readings.py --config <file> --traffic <mix> --seeds ...
 
 Per seed it sets the cell up as a benchmark run does, serves
 ``--batches`` batches (each finishes the mix's longest requests),
@@ -13,12 +14,19 @@ at the same positions, each with the verdict its ``far_share`` gets at the
 configuration's limit (the control's has to be false).  One JSON line per seed goes
 to stdout and to ``chiprun_out/readings-<cell>.jsonl``.  It is no part
 of a benchmark run; ``--rehearse`` reads at the smoke size.
+
+``--config`` (a configuration file, relative to the repository's root)
+with ``--traffic`` (a mix under ``traffic/``) reads a configuration,
+at whatever depth its file states, on one chip, before any cell of
+``BENCHMARK.json`` lists it; its lines are named ``<file's
+name>.<mix>``.
 """
 import argparse
 import json
 import os
 import sys
 import time
+from typing import Optional
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
@@ -26,18 +34,29 @@ import check  # noqa: E402
 import run as bench  # noqa: E402
 
 
-def main(argv=None) -> int:
+def main(argv=None, root: Optional[str] = None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
-    ap.add_argument("--workload", required=True)
+    which = ap.add_mutually_exclusive_group(required=True)
+    which.add_argument("--workload")
+    which.add_argument("--config")
+    ap.add_argument("--traffic")
     ap.add_argument("--seeds", type=int, nargs="+", required=True)
     ap.add_argument("--batches", type=int, default=1)
     ap.add_argument("--rehearse", action="store_true")
     args = ap.parse_args(argv)
-    spec = bench.load_spec(bench.REPO, args.workload, args.rehearse)
+    if (args.config is None) != (args.traffic is None):
+        ap.error("--config and --traffic go together")
+    root = root or bench.REPO
+    if args.config:
+        spec = bench.config_spec(root, args.config, args.traffic,
+                                 args.rehearse)
+    else:
+        spec = bench.load_spec(root, args.workload, args.rehearse)
+    name = spec["cell"]["name"]
     devs = bench._device_or_exit(spec["cell"]["chips"], args.rehearse)
-    out_dir = os.path.join(bench.REPO, "chiprun_out")
+    out_dir = os.path.join(root, "chiprun_out")
     os.makedirs(out_dir, exist_ok=True)
-    path = os.path.join(out_dir, f"readings-{args.workload}.jsonl")
+    path = os.path.join(out_dir, f"readings-{name}.jsonl")
     for seed in args.seeds:
         t0 = time.time()
         cell = bench.Cell(spec, seed)
@@ -48,7 +67,7 @@ def main(argv=None) -> int:
         t = time.time()
         finished, pick, gaps = cell.gaps(
             batches, controls=(spec["config"]["check"]["control"],))
-        row = {"cell": args.workload, "seed": seed, "setup_s": setup,
+        row = {"cell": name, "seed": seed, "setup_s": setup,
                "host": cell.host, "batch_walls": [b.wall_s for b in batches],
                "tokens": sum(b.report.total_tokens for b in batches),
                "peak_bytes": peak, "bad": check.bad_requests(

@@ -14,13 +14,11 @@ It computes what the configuration states, written out directly:
   arithmetic in float32 at ``highest`` matmul precision, and its result
   rounded to the configuration's dtype (bfloat16) wherever a model of
   that dtype keeps a tensor: after each norm, projection, RoPE,
-  attention, gate and residual add, and the logits.  The operations:
-  RMS norms with gain (1 + w), RoPE on the two halves of each head,
-  causal softmax attention over the unpadded heads, SwiGLU, and for
-  mamba2 two causal depthwise convolutions with SiLU, the selective-state
-  recurrence run one step at a time, the SiLU gate and the gated RMS
-  norm; the LM head is the embedding's transpose where the configuration
-  ties them.
+  attention, gate and residual add, and the logits.  The embedding, the
+  final RMS norm with gain (1 + w) and the LM head are here, the head
+  the embedding's transpose where the configuration ties them; the
+  layers between them are the configuration's architecture's
+  (``arch/<name>.py`` ``forward``), written with the helpers here.
 
 ``act`` names another dtype for every one of those roundings but the
 logits'.  A configuration's ``check.act`` is the precision the program
@@ -127,83 +125,27 @@ def rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def _dense_layer(x, p, m, r, lin):
-    S = x.shape[0]
-    H, dh, eps = m["n_heads"], m["d_head"], m["norm_eps"]
-    a = p["attn"]
-    h = r(rms_norm(x, p["ln1"], eps))
-    q = r(lin(h, a["wq"][:, :H * dh])).reshape(S, H, dh)
-    k = r(lin(h, a["wk"][:, :H * dh])).reshape(S, H, dh)
-    v = r(lin(h, a["wv"][:, :H * dh])).reshape(S, H, dh)
-    q = r(rope(q, m["rope_theta"]))
-    k = r(rope(k, m["rope_theta"]))
-    s = jnp.einsum("qhd,khd->hqk", q * dh ** -0.5, k, precision=HI)
-    causal = jnp.tril(jnp.ones((S, S), bool))
-    s = jnp.where(causal[None], s, -jnp.inf)
-    o = jnp.einsum("hqk,khd->qhd", jax.nn.softmax(s, -1), v, precision=HI)
-    o = r(o).reshape(S, H * dh)
-    x = r(x + r(lin(o, a["wo"][:H * dh])))
-    h = r(rms_norm(x, p["ln2"], eps))
-    g = r(lin(h, p["mlp"]["w1"]))
-    u = r(lin(h, p["mlp"]["w3"]))
-    f = r(jax.nn.silu(g) * u)
-    return r(x + r(lin(f, p["mlp"]["w2"])))
-
-
-def _causal_conv(u, w, b):
-    W = w.shape[0]
-    up = jnp.pad(u, ((W - 1, 0), (0, 0)))
-    S = u.shape[0]
-    out = sum(up[i:i + S] * w[i].astype(jnp.float32) for i in range(W))
-    return jax.nn.silu(out + b.astype(jnp.float32))
-
-
-def _mamba_layer(x, p, m, r, lin):
-    S = x.shape[0]
-    mb = p["mamba"]
-    n, P, eps = m["ssm_state"], m["ssm_head_dim"], m["norm_eps"]
-    h = r(rms_norm(x, p["ln1"], eps))
-    z = r(lin(h, mb["w_z"]))
-    xc = r(lin(h, mb["w_x"]))
-    bc = r(lin(h, mb["w_bc"]))
-    dtr = r(lin(h, mb["w_dt"]))
-    xc = r(_causal_conv(xc, mb["conv_x"], mb["conv_bias_x"]))
-    bc = r(_causal_conv(bc, mb["conv_b"], mb["conv_bias_b"]))
-    B, C = bc[:, :n], bc[:, n:]
-    H = xc.shape[1] // P
-    xh = xc.reshape(S, H, P)
-    dt = jax.nn.softplus(dtr + mb["dt_bias"])                      # (S, H)
-    A = -jnp.exp(mb["A_log"])
-
-    def step(state, inp):
-        x_t, b_t, c_t, dt_t = inp
-        state = (state * jnp.exp(dt_t * A)[:, None, None]
-                 + dt_t[:, None, None] * x_t[:, :, None] * b_t[None, None, :])
-        y_t = jnp.einsum("hpn,n->hp", state, c_t, precision=HI)
-        return state, y_t + mb["D_skip"][:, None] * x_t
-
-    _, y = jax.lax.scan(step, jnp.zeros((H, P, n), jnp.float32),
-                        (xh, B, C, dt))
-    y = r(r(y.reshape(S, H * P)) * jax.nn.silu(z))
-    y = r(rms_norm(y, mb["gate_norm"], eps))
-    return r(x + r(lin(y, mb["out_proj"])))
-
-
-@functools.partial(jax.jit, static_argnames=("model_items", "n_out", "act"))
-def logits(params, tokens, start, *, model_items, n_out: int,
-           act: Optional[str] = None):
-    """Logits (n_out, V) of rows start .. start+n_out-1 of the sequence
-    ``tokens`` (S,), run causally from position 0."""
-    m = dict(model_items)
-    r = _rounder(act or m["dtype"])
-    lin = cim_linear
-    layer = _mamba_layer if m["family"] == "ssm" else _dense_layer
-    x = r(params["embed"][tokens].astype(jnp.float32))
-
+def uniform_stack(layer, x, layers, m, r, lin):
+    """``layer(x, p, m, r, lin)`` over the layers stacked on the leading
+    axis of ``layers``, in order."""
     def body(x, p):
         return layer(x, p, m, r, lin), None
 
-    x, _ = jax.lax.scan(body, x, params["layers"])
+    x, _ = jax.lax.scan(body, x, layers)
+    return x
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("arch", "model_items", "n_out", "act"))
+def logits(params, tokens, start, *, arch, model_items, n_out: int,
+           act: Optional[str] = None):
+    """Logits (n_out, V) of rows start .. start+n_out-1 of the sequence
+    ``tokens`` (S,), run causally from position 0 through the layers of
+    ``arch`` (the architecture's module)."""
+    m = dict(model_items)
+    r = _rounder(act or m["dtype"])
+    x = r(params["embed"][tokens].astype(jnp.float32))
+    x = arch.forward(x, params["layers"], m, r, cim_linear)
     h = r(rms_norm(x, params["ln_f"], m["norm_eps"]))
     h = jax.lax.dynamic_slice_in_dim(h, start, n_out, axis=0)
     head = (params["embed"].T if m["tie_embeddings"]

@@ -85,7 +85,8 @@ def compile_cell(cell: str) -> None:
     jax.default_backend = lambda: "tpu"
     try:
         params = on_chip(jax.eval_shape(
-            lambda: lm.pack_cim_params(weights.make_params(model, 0), cfg)))
+            lambda: lm.pack_cim_params(
+                weights.make_params(spec["arch"], model, 0), cfg)))
         cache = on_chip(jax.eval_shape(
             lambda: lm.init_paged_cache(cfg, slots, layout.n_blocks,
                                         layout.block_size, layout.n_tbl)))

@@ -29,7 +29,9 @@ the last lines on stderr.  With no TPU, or fewer chips than the cell
 asks for, the run exits 2 and prints no result.
 
 Everything about a cell is found by name: ``configs/<config>.json``
-(as ``BENCHMARK.json`` names it), ``traffic/<traffic>.json`` and
+(as ``BENCHMARK.json`` names it), the architecture that configuration
+names under its ``arch`` key, ``arch/<arch>.py`` (its weights,
+reference layers and work counts), ``traffic/<traffic>.json`` and
 ``metrics/<metric>.py``.  ``--rehearse`` runs a cell at the
 configuration's ``smoke`` size on any backend, for rehearsal on a CPU;
 it prints counts only, never a time or a device metric.
@@ -71,19 +73,34 @@ def _merge(base: dict, over: dict) -> dict:
     return out
 
 
-def load_spec(root: str, workload: str, rehearse: bool) -> Dict[str, Any]:
-    """The cell's entry, configuration, traffic mix and metric entries."""
+def _load_module(bench_dir: str, kind: str, name: str):
+    """``<bench_dir>/<kind>/<name>.py``, loaded from its path."""
+    path = os.path.join(bench_dir, kind, name + ".py")
+    if not os.path.isfile(path):
+        raise SystemExit(f"no {kind} module {path}")
+    spec = importlib.util.spec_from_file_location(
+        f"bench_{kind}_" + name.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def _load_reader(bench_dir: str, name: str):
+    return _load_module(bench_dir, "metrics", name)
+
+
+def _benchmark(root: str) -> dict:
     with open(os.path.join(root, "BENCHMARK.json")) as f:
-        bench = json.load(f)
-    cells = {w["name"]: w for w in bench["workloads"]}
-    if workload not in cells:
-        raise SystemExit(f"unknown workload {workload!r}; have "
-                         f"{sorted(cells)}")
-    cell = cells[workload]
+        return json.load(f)
+
+
+def _spec(root: str, bench: dict, cell: dict, config_file: str,
+          rehearse: bool) -> Dict[str, Any]:
     bench_dir = os.path.join(root, bench["paths"][0])
-    conf_entry = {c["name"]: c for c in bench["configs"]}[cell["config"]]
-    with open(os.path.join(root, conf_entry["file"])) as f:
+    with open(os.path.join(root, config_file)) as f:
         config = json.load(f)
+    if "arch" not in config:
+        raise SystemExit(f"{config_file}: the configuration names no 'arch'")
     import workload as wl
     mix = wl.load_mix(os.path.join(bench_dir, "traffic",
                                    cell["traffic"] + ".json"))
@@ -92,10 +109,35 @@ def load_spec(root: str, workload: str, rehearse: bool) -> Dict[str, Any]:
         config = _merge(config, {k: v for k, v in smoke.items()
                                  if k in ("model", "serving", "check")})
         config["traffic_scale"] = smoke.get("traffic_scale", 1.0)
-    in_cell = lambda m: "workloads" not in m or workload in m["workloads"]
+    name = cell["name"]
+    in_cell = lambda m: "workloads" not in m or name in m["workloads"]
     return dict(cell=cell, config=config, mix=mix, bench_dir=bench_dir,
+                arch=_load_module(bench_dir, "arch", config["arch"]),
                 end_to_end=[m for m in bench["end_to_end"] if in_cell(m)],
                 per_layer=[m for m in bench["per_layer"] if in_cell(m)])
+
+
+def load_spec(root: str, workload: str, rehearse: bool) -> Dict[str, Any]:
+    """The cell's entry, configuration, traffic mix, architecture module
+    and metric entries."""
+    bench = _benchmark(root)
+    cells = {w["name"]: w for w in bench["workloads"]}
+    if workload not in cells:
+        raise SystemExit(f"unknown workload {workload!r}; have "
+                         f"{sorted(cells)}")
+    cell = cells[workload]
+    conf_entry = {c["name"]: c for c in bench["configs"]}[cell["config"]]
+    return _spec(root, bench, cell, conf_entry["file"], rehearse)
+
+
+def config_spec(root: str, config_file: str, traffic: str,
+                rehearse: bool) -> Dict[str, Any]:
+    """As ``load_spec``, for a configuration file (relative to ``root``)
+    under a traffic mix, on one chip, whether or not a cell lists them."""
+    name = os.path.splitext(os.path.basename(config_file))[0]
+    cell = {"name": f"{name}.{traffic}", "config": name, "traffic": traffic,
+            "chips": 1}
+    return _spec(root, _benchmark(root), cell, config_file, rehearse)
 
 
 @dataclasses.dataclass
@@ -118,15 +160,7 @@ class Run:
     peaks: Optional[dict] = None  # peaks.json row of this device kind
     trace: Any = None             # trace_reduce.Trace of the traced batch
     window: Any = None            # (t0, t1) of that batch on the host clock
-
-
-def _load_reader(bench_dir: str, name: str):
-    path = os.path.join(bench_dir, "metrics", name + ".py")
-    spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    arch: Any = None              # the configuration's arch/<name>.py module
 
 
 def _device_or_exit(chips: int, rehearse: bool):
@@ -152,7 +186,7 @@ def plan(spec: Dict[str, Any], seed: int):
     traffic = wl.Traffic(
         spec["mix"], seed, sv["slots"], model["vocab_size"],
         sv["prompt_cap"], sv["max_new_cap"],
-        multiple=sv["prefill_chunk"] if model["family"] == "ssm" else 1,
+        multiple=spec["arch"].prompt_multiple(model, sv),
         scale=config.get("traffic_scale", 1.0))
     return layout, traffic
 
@@ -174,7 +208,7 @@ class Cell:
         import weights
 
         self.spec, self.seed, self.Request = spec, seed, Request
-        config = spec["config"]
+        self.arch, config = spec["arch"], spec["config"]
         self.model, sv = config["model"], config["serving"]
         self.cache_dir = enable_compile_cache()
         # every program of the run goes into the cache, not only those
@@ -187,7 +221,8 @@ class Cell:
 
         host = self.host = {}
         t = time.time()
-        raw = jax.block_until_ready(weights.make_params(self.model, seed))
+        raw = jax.block_until_ready(
+            weights.make_params(self.arch, self.model, seed))
         host["weights_s"] = time.time() - t
         t = time.time()
         params = jax.block_until_ready(lm.pack_cim_params(raw, cfg))
@@ -250,9 +285,9 @@ class Cell:
                                  got.get(rid, np.zeros(0, np.int32))))
         lim = self.spec["config"]["check"]
         pick = check.sample(finished, lim["sample"], self.seed)
-        params = weights.make_params(self.model, self.seed)
+        params = weights.make_params(self.arch, self.model, self.seed)
         gaps = check.logit_gaps(
-            params, self.model,
+            self.arch, params, self.model,
             [(finished[i][1], finished[i][3]) for i in pick],
             self.prompt_cap + self.new_cap, self.new_cap, lim["act"], controls)
         del params
@@ -326,7 +361,7 @@ def main(argv=None, root: Optional[str] = None) -> int:
     run = Run(cell=spec["cell"]["name"], model=cell.model,
               layout=cell.layout, host=host,
               batches=batches, memory=memory, peaks=peaks, trace=trace,
-              window=window)
+              window=window, arch=cell.arch)
 
     cell.free()
     t = time.time()

@@ -82,7 +82,8 @@ def reduce_batch(spec, cell, batch, trace, text: str, peaks) -> dict:
         f"{k} {v:.6f}" for k, v in idle))
     run = bench.Run(cell=spec["cell"]["name"], model=cell.model,
                     layout=cell.layout, host=cell.host, batches=[batch],
-                    memory={}, peaks=peaks, trace=trace.base, window=window)
+                    memory={}, peaks=peaks, trace=trace.base, window=window,
+                    arch=cell.arch)
     run.scope_trace, run.loop_text = trace, text
     metrics = {}
     for name in READERS:

@@ -38,12 +38,14 @@ def test_reads_nothing_without_attention_or_counter():
 
 def test_rehearse(tmp_path, capsys):
     bench_dir = tmp_path / "bench"
-    for d in ("configs", "traffic", "metrics"):
+    for d in ("configs", "traffic", "metrics", "arch"):
         (bench_dir / d).mkdir(parents=True)
     (bench_dir / "configs" / "tiny.json").write_text(json.dumps(TINY))
     (bench_dir / "traffic" / "burst.json").write_text(json.dumps(BURST))
     shutil.copy(os.path.join(HERE, "metrics", "paged_attn_live_frac.py"),
                 bench_dir / "metrics" / "paged_attn_live_frac.py")
+    shutil.copy(os.path.join(HERE, "arch", TINY["arch"] + ".py"),
+                bench_dir / "arch")
     (tmp_path / "BENCHMARK.json").write_text(json.dumps({
         "command": ["python3", "bench/run.py"], "paths": ["bench"],
         "run_seconds": 10,

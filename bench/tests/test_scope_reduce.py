@@ -230,13 +230,15 @@ def test_readers_rehearse(tmp_path, capsys):
     program's counters read numbers, the device readers nothing."""
     from test_data_driven import BURST, TINY
     bench_dir = tmp_path / "bench"
-    for d in ("configs", "traffic", "metrics"):
+    for d in ("configs", "traffic", "metrics", "arch"):
         (bench_dir / d).mkdir(parents=True)
     (bench_dir / "configs" / "tiny.json").write_text(json.dumps(TINY))
     (bench_dir / "traffic" / "burst.json").write_text(json.dumps(BURST))
     names = ("decode_step_ms", "prefill_chunk_ms", "sched_device_frac",
              "host_overhead_s", "window_compiles")
     here = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    shutil.copy(os.path.join(here, "arch", TINY["arch"] + ".py"),
+                bench_dir / "arch")
     for n in names:
         shutil.copy(os.path.join(here, "metrics", n + ".py"),
                     bench_dir / "metrics" / (n + ".py"))

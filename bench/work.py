@@ -7,7 +7,9 @@ not move them):
 * A CIM projection of an (M, K) activation by a (K, N) weight is
   2*M*K*N operations at the chip's int8 peak.  Projections that share
   an input count as one call of the summed N (QKV; gate and up; the
-  four mamba2 input projections), as the program fuses them.
+  four mamba2 input projections), as the program fuses them.  A site
+  that only a share of the rows pass through (a routed expert) counts
+  that share of them, its ``row_share``.
 * Its bytes are 1 per weight per model pass (every decode step and
   every prefill chunk reads all weights once), plus 1 per activation
   element in and 4 per int32 element out.
@@ -17,16 +19,22 @@ not move them):
   served tokens: P - S + n - 1, where S prompt tokens come from shared
   cache blocks (the last served token is never fed back).
 * Attention at context c costs 4*H*dh*c operations per layer per row
-  (QK and PV) at the bf16 peak; a decode row at context c reads
-  c * L * H * dh * 2 (K and V) * 2 (bf16) bytes of cache.
+  (QK and PV) at the bf16 peak; a decode row at context c reads the
+  cache's bytes per token c times.
 * The LM head is 2*D*V operations per served token at the bf16 peak.
-* The mamba2 recurrence is 5*H*P*N operations per row per layer (decay,
-  input outer product, add; output contraction) and the two causal
-  convolutions 2*W*(d_inner + 2N), at the bf16 peak.
+* Recurrent state (the mamba2 recurrence and its convolutions) costs
+  what its architecture counts, at the bf16 peak.
+
+Each architecture (``arch/<name>.py``) counts its own layers:
+``cim_sites(m, padded)``, every CIM call of one whole model pass as
+(name, K, N, row_share); ``kv_bytes_per_token(m, padded)``;
+``attn_ops_rows(m, first, last, padded)``, attention operations of rows
+at positions first..last (context position + 1); ``state_ops(m, rows)``.
+The functions here take that module as ``arch``.
 """
 from __future__ import annotations
 
-from typing import List, Tuple
+from typing import Tuple
 
 
 def heads(model: dict, padded: bool = False) -> int:
@@ -38,64 +46,21 @@ def heads(model: dict, padded: bool = False) -> int:
     return -(-model["n_heads"] // pad) * pad
 
 
-def cim_sites(model: dict, padded: bool = False) -> List[Tuple[str, int, int]]:
-    """(name, K, N) of each CIM call of one layer."""
-    d = model["d_model"]
-    if model["family"] == "ssm":
-        di = model.get("ssm_expand", 2) * d
-        n = model["ssm_state"]
-        h = di // model.get("ssm_head_dim", 64)
-        return [("in_proj", d, 2 * di + 2 * n + h), ("out_proj", di, d)]
-    if model["family"] != "dense" or model["n_kv_heads"] != model["n_heads"]:
-        raise ValueError("work counts cover mamba2 and MHA dense models")
-    hd = heads(model, padded) * model["d_head"]
-    f = model["d_ff"]
-    return [("qkv", d, 3 * hd), ("wo", hd, d), ("gate_up", d, 2 * f),
-            ("down", f, d)]
-
-
-def cim_weights(model: dict, padded: bool = False) -> int:
+def cim_weights(arch, model: dict, padded: bool = False) -> int:
     """CIM weights of the whole model: the bytes one pass reads."""
-    return model["n_layers"] * sum(k * n for _, k, n in
-                                   cim_sites(model, padded))
+    return sum(k * n for _, k, n, _ in arch.cim_sites(model, padded))
 
 
-def cim_ops(model: dict, rows: int, padded: bool = False) -> int:
-    return 2 * rows * cim_weights(model, padded)
+def cim_ops(arch, model: dict, rows: int, padded: bool = False):
+    return 2 * rows * sum(k * n * share for _, k, n, share in
+                          arch.cim_sites(model, padded))
 
 
-def cim_bytes(model: dict, rows: int, passes: int,
-              padded: bool = False) -> int:
-    act = model["n_layers"] * sum(k + 4 * n for _, k, n in
-                                  cim_sites(model, padded))
-    return passes * cim_weights(model, padded) + rows * act
-
-
-def kv_bytes_per_token(model: dict, padded: bool = False) -> int:
-    if model["family"] == "ssm":
-        return 0
-    return model["n_layers"] * heads(model, padded) * model["d_head"] * 4
-
-
-def attn_ops_rows(model: dict, first: int, last: int,
-                  padded: bool = False) -> int:
-    """Attention operations of rows at positions first..last (context
-    position + 1)."""
-    if model["family"] == "ssm" or last < first:
-        return 0
-    ctx = (last + 1) * (last + 2) // 2 - first * (first + 1) // 2
-    return 4 * model["n_layers"] * heads(model, padded) * model["d_head"] * ctx
-
-
-def ssm_ops(model: dict, rows: int) -> int:
-    if model["family"] != "ssm":
-        return 0
-    d = model["d_model"]
-    di = model.get("ssm_expand", 2) * d
-    n, p = model["ssm_state"], model.get("ssm_head_dim", 64)
-    w = model.get("ssm_conv_width", 4)
-    per = 5 * (di // p) * p * n + 2 * w * (di + 2 * n)
-    return model["n_layers"] * per * rows
+def cim_bytes(arch, model: dict, rows: int, passes: int,
+              padded: bool = False):
+    act = sum((k + 4 * n) * share for _, k, n, share in
+              arch.cim_sites(model, padded))
+    return passes * cim_weights(arch, model, padded) + rows * act
 
 
 def head_ops(model: dict, served: int) -> int:
@@ -106,27 +71,27 @@ def request_rows(prompt_len: int, served: int, shared_tokens: int = 0) -> int:
     return prompt_len - shared_tokens + served - 1
 
 
-def step_work(model: dict, requests) -> Tuple[int, int]:
+def step_work(arch, model: dict, requests) -> Tuple[int, int]:
     """(int8 ops, bf16 ops) the model needs for ``requests``, an iterable
     of (prompt_len, served) pairs: every prompt and served token's row,
     none taken from a cache."""
     i8 = b16 = 0
     for plen, n in requests:
         rows = request_rows(plen, n)
-        i8 += cim_ops(model, rows)
-        b16 += (attn_ops_rows(model, 0, rows - 1) + ssm_ops(model, rows)
-                + head_ops(model, n))
+        i8 += cim_ops(arch, model, rows)
+        b16 += (arch.attn_ops_rows(model, 0, rows - 1)
+                + arch.state_ops(model, rows) + head_ops(model, n))
     return i8, b16
 
 
-def decode_kv_bytes(model: dict, prompt_len: int, served: int) -> int:
+def decode_kv_bytes(arch, model: dict, prompt_len: int, served: int) -> int:
     """Cache bytes the decode rows of one request must read: the row
     that yields served token i + 1 sees context prompt_len + i."""
     ctx = sum(prompt_len + i for i in range(1, served))
-    return ctx * kv_bytes_per_token(model)
+    return ctx * arch.kv_bytes_per_token(model)
 
 
-def decode_attn_ops(model: dict, prompt_len: int, served: int) -> int:
+def decode_attn_ops(arch, model: dict, prompt_len: int, served: int) -> int:
     if served < 2:
         return 0
-    return attn_ops_rows(model, prompt_len, prompt_len + served - 2)
+    return arch.attn_ops_rows(model, prompt_len, prompt_len + served - 2)
