@@ -595,7 +595,7 @@ def hybrid_mac_fast_gemm_prepacked(
     # telemetry tap (obs/taps.py): count ADC codes the clip saturates.
     # Trace-time flag -- with no collector open (telemetry off) the
     # lowered program is unchanged
-    tap_clip = taps.active()
+    tap_clip = taps.active("adc_clip")
     # fault injection (resilience/faults.py): same static-flag contract.
     # Drift perturbs only the ANALOG quantities -- the a_real partial
     # before conversion and the SAR conversion itself -- never the exact

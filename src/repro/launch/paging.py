@@ -84,9 +84,7 @@ class PagedLayout:
         serve benchmark reports per row."""
         nb = self.n_blocks if n_blocks is None else n_blocks
         per_row = cfg.padded_kv_heads * cfg.head_dim * dtype_bytes
-        n_kv_layers = 0
-        if cfg.family in ("dense", "moe", "vlm", "audio"):
-            n_kv_layers += 2 * cfg.n_layers                  # k + v
+        n_kv_layers = 2 * cfg.attn_layers                    # k + v
         if cfg.family == "hybrid" and cfg.shared_attn_period:
             n_kv_layers += 2 * (cfg.n_layers // cfg.shared_attn_period)
         return nb * self.block_size * per_row * n_kv_layers

@@ -79,6 +79,7 @@ assignment -- like silicon, where each slot maps to a physical macro bank
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -88,6 +89,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..models import lm
+from ..models.layers import MOE_TAPS
 from ..models.config import ModelConfig
 from ..obs import taps
 from ..obs import rings as obs_rings
@@ -147,6 +149,11 @@ class ServeReport:
     # paged decode-attention geometry; 0 when unpaged or attention-free
     block_size: int = 0
     n_tbl: int = 0
+    # held-expert layers, over every MoE layer of every decode step and
+    # prefill chunk: (live row, held expert) pairs the router chose, and
+    # rows the held experts' CIM calls computed; 0 without such layers
+    moe_routed_rows: int = 0
+    moe_expert_rows: int = 0
 
     @property
     def total_tokens(self) -> int:
@@ -346,6 +353,9 @@ class ContinuousBatchingScheduler:
                           if draft_plan is not None else cfg)
 
         self.paged = paged
+        # held-expert layers count their routed and computed rows through
+        # taps (models/layers.py); the loop sums them in its carry
+        self._moe = bool(cfg.layer_count("E"))
         self.prefix_sharing = prefix_sharing
         if paged is not None:
             C = prefill_chunk if prefill_chunk is not None else prompt_len
@@ -742,14 +752,11 @@ class ContinuousBatchingScheduler:
             plen = q_meta[qidx, 3]
             start = st["cache"]["pos"][slot]
             chunk = jax.lax.dynamic_slice(q_toks, (qidx, start), (1, C))
-            if self.obs is not None:
-                with taps.collect() as fr:
-                    logits, cache = lm.prefill_chunk_into_slot(
-                        params, cfg, chunk, st["cache"], slot)
-                clip = taps.drain_sum(fr, "adc_clip")
-            else:
+            with self._taps() as fr:
                 logits, cache = lm.prefill_chunk_into_slot(
                     params, cfg, chunk, st["cache"], slot)
+            if self.obs is not None:
+                clip = taps.drain_sum(fr, "adc_clip")
             done = (start + C) >= plen
             row = jnp.clip(plen - 1 - start, 0, C - 1)
             lg = jax.lax.dynamic_slice(
@@ -785,6 +792,7 @@ class ContinuousBatchingScheduler:
                                         do=done)
                 o = obs_rings.ctr_add(o, obs_rings.CTR_ADC_CLIP, clip)
                 upd["obs"] = dict(o, tick_tok=done.astype(jnp.int32))
+            upd.update(self._moe_counts(c, fr))
             return dict(c, st=st, last_pf=jnp.bool_(True),
                         n_pf=c["n_pf"] + 1,
                         pf_done=c["pf_done"].at[qidx].set(
@@ -827,10 +835,13 @@ class ContinuousBatchingScheduler:
         @scoped("sched.step")
         def step(c):
             if self.obs is None:
-                return step_core(c)
+                with self._taps() as fr:
+                    c2 = step_core(c)
+                return dict(c2, **self._moe_counts(c, fr))
             n_gen0 = jnp.sum(c["st"]["n_gen"])
             with taps.collect() as fr:
                 c2 = step_core(c)
+            c2 = dict(c2, **self._moe_counts(c, fr))
             # n_gen is monotone across a decode step / spec round, so
             # the delta is exactly the tokens this iteration emitted
             # (variable 1..K+1 per live slot in spec mode)
@@ -912,6 +923,9 @@ class ContinuousBatchingScheduler:
                        n_accepted=c["n_accepted"])
             if self.paged is not None:
                 out.update(n_pf=c["n_pf"], peak_blocks=c["peak_blocks"])
+            if self._moe:
+                out.update(moe_routed=c["moe_routed"],
+                           moe_computed=c["moe_computed"])
             if self.obs is not None:
                 out["obs"] = c["obs"]
             return out
@@ -1080,9 +1094,31 @@ class ContinuousBatchingScheduler:
                 pf_done=jnp.zeros((n_queue,), jnp.bool_),
                 req_tables=jnp.zeros((n_queue, self.paged.n_tbl),
                                      jnp.int32))
+        if self._moe:
+            carry.update(moe_routed=_i32(0), moe_computed=_i32(0))
         if self.obs is not None and with_obs:
             carry["obs"] = obs_rings.init_obs_state(self.obs)
         return carry
+
+    def _taps(self):
+        """The tap collector a model pass of the loop runs under: every
+        name with telemetry on, the held-expert row counts where the
+        model has held-expert layers, none otherwise (a frame that is
+        never opened adds no operation)."""
+        if self.obs is not None:
+            return taps.collect()
+        if self._moe:
+            return taps.collect(MOE_TAPS)
+        return contextlib.nullcontext({})
+
+    def _moe_counts(self, c, frame) -> Dict[str, jax.Array]:
+        """The carry's held-expert row counts plus what a pass emitted."""
+        if not self._moe:
+            return {}
+        return dict(
+            moe_routed=c["moe_routed"] + taps.drain_sum(frame, MOE_TAPS[0]),
+            moe_computed=c["moe_computed"] + taps.drain_sum(frame,
+                                                            MOE_TAPS[1]))
 
     # -- host-side staging ---------------------------------------------
 
@@ -1257,6 +1293,9 @@ class ContinuousBatchingScheduler:
                              n_pf=int(res.get("n_pf", 0)),
                              peak_blocks=int(res.get("peak_blocks", 0)),
                              n_iter=n_iter, **self._attn_geometry())
+        if self._moe:
+            report.moe_routed_rows = int(res["moe_routed"])
+            report.moe_expert_rows = int(res["moe_computed"])
         if self.obs is not None:
             report.obs = obs_rings.harvest_obs(
                 self.obs, jax.device_get(res["obs"]), n_iter=n_iter,
@@ -1316,6 +1355,9 @@ class ContinuousBatchingScheduler:
             n_pf=int(c.get("n_pf", 0)),
             peak_blocks=int(c.get("peak_blocks", 0)), n_iter=len(branches),
             **self._attn_geometry())
+        if self._moe:
+            report.moe_routed_rows = int(c["moe_routed"])
+            report.moe_expert_rows = int(c["moe_computed"])
         if self.obs is not None:
             # the instrumented runner's carry keeps the rings inline
             # (the host round-trips it), so harvest reads them directly

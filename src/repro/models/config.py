@@ -10,6 +10,9 @@ if TYPE_CHECKING:  # annotation only -- models must not import repro.plan
     from ..plan.plan import DeploymentPlan
 
 
+LAYER_KINDS = ("M", "E", "*")     # layer_types: mamba2, experts, attention
+
+
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
@@ -24,6 +27,7 @@ class ModelConfig:
 
     # attention features
     rope_theta: float = 10000.0
+    use_rope: bool = True              # nemotron_h attention: no rotary
     qk_norm: bool = False              # qwen3
     attn_softcap: Optional[float] = None    # gemma2: 50.0
     logit_softcap: Optional[float] = None   # gemma2: 30.0
@@ -41,6 +45,13 @@ class ModelConfig:
     shared_expert_d_ff: int = 0
     capacity_factor: float = 1.25
     router_aux_loss: float = 0.001
+    # expert parallelism (the "E" layers of a layer_types stack): a
+    # sigmoid router with a choice bias scores moe_router_experts experts
+    # (0 -> n_experts), and this chip holds the n_experts of them that
+    # start at moe_first_expert
+    moe_router_experts: int = 0
+    moe_first_expert: int = 0
+    moe_routed_scaling: float = 1.0
 
     # SSM (mamba2 / SSD)
     ssm_state: int = 0
@@ -48,9 +59,14 @@ class ModelConfig:
     ssm_head_dim: int = 64
     ssm_conv_width: int = 4
     ssm_chunk: int = 256
+    ssm_n_heads: int = 0               # 0 -> d_inner = ssm_expand * d_model
+    ssm_n_groups: int = 1              # B/C groups, each serving H/G heads
 
     # hybrid (zamba2): one SHARED attention block applied every N ssm layers
     shared_attn_period: int = 0
+    # hybrid (nemotron_h): one character per layer -- "M" mamba2 mixer,
+    # "E" held-expert MoE, "*" attention -- each a pre-norm residual block
+    layer_types: str = ""
 
     # frontends (stubs per the brief: precomputed patch/frame embeddings)
     frontend: Optional[str] = None     # "siglip_stub" | "encodec_stub"
@@ -97,6 +113,17 @@ class ModelConfig:
     # pads vocab the same way).  reduced() sets 1 (no pad on CPU smoke).
     tp_head_pad: int = 16
 
+    def __post_init__(self):
+        if self.layer_types:
+            if self.family != "hybrid" or self.shared_attn_period:
+                raise ValueError("layer_types builds a 'hybrid' stack "
+                                 "without a shared attention block")
+            if (len(self.layer_types) != self.n_layers
+                    or set(self.layer_types) - set(LAYER_KINDS)):
+                raise ValueError(
+                    f"layer_types {self.layer_types!r} must give one of "
+                    f"{LAYER_KINDS} for each of the {self.n_layers} layers")
+
     # ----- derived -----
     @property
     def head_dim(self) -> int:
@@ -121,11 +148,50 @@ class ModelConfig:
 
     @property
     def d_inner(self) -> int:
+        if self.ssm_n_heads:
+            return self.ssm_n_heads * self.ssm_head_dim
         return self.ssm_expand * self.d_model
 
     @property
     def ssm_heads(self) -> int:
         return self.d_inner // self.ssm_head_dim
+
+    @property
+    def ssm_bc_dim(self) -> int:
+        """Width of the B and C streams together: 2 * groups * state."""
+        return 2 * self.ssm_n_groups * self.ssm_state
+
+    @property
+    def act_dtype(self) -> str:
+        """What the residual stream, the KV cache and the conv state are
+        kept in: float32 for a ``layer_types`` stack, whose router top-k
+        and macro input quantization are discontinuous, so that rounding
+        to the weights' dtype would move them; else the weights' dtype."""
+        return "float32" if self.layer_types else self.dtype
+
+    @property
+    def router_experts(self) -> int:
+        return self.moe_router_experts or self.n_experts
+
+    @property
+    def attn_layers(self) -> int:
+        """Layers that keep a KV cache of their own (the zamba2 shared
+        block's invocations aside)."""
+        if self.layer_types:
+            return self.layer_count("*")
+        return (self.n_layers if self.family in ("dense", "moe", "vlm",
+                                                  "audio") else 0)
+
+    @property
+    def ssm_layers(self) -> int:
+        """Layers that keep SSM and conv state."""
+        if self.layer_types:
+            return self.layer_count("M")
+        return self.n_layers if self.family in ("ssm", "hybrid") else 0
+
+    def layer_count(self, kind: str) -> int:
+        """Layers of one ``layer_types`` kind ("M", "E", "*")."""
+        return self.layer_types.count(kind)
 
     def layer_is_local(self, i: int) -> bool:
         return self.layer_pattern == "local_global" and i % 2 == 0

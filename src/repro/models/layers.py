@@ -27,6 +27,7 @@ from ..core.ccim import DEFAULT_CONFIG
 from ..core.engine import (CimEngine, FusedPackedCimWeights,
                            PackedCimWeights)
 from ..kernels.paged_attn import ops as paged_attn_ops
+from ..obs import taps
 from ..obs.scopes import scoped
 from .config import ModelConfig
 
@@ -359,8 +360,9 @@ def _qkv(p, x, cfg: ModelConfig, positions, path="attn"):
     if cfg.qk_norm:
         q = rms_norm(q, p["q_norm"], cfg.norm_eps)
         k = rms_norm(k, p["k_norm"], cfg.norm_eps)
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
+    if cfg.use_rope:
+        q = rope(q, positions, cfg.rope_theta)
+        k = rope(k, positions, cfg.rope_theta)
     return q, k, v
 
 
@@ -783,13 +785,120 @@ def moe_apply(p: Params, x: Array, cfg: ModelConfig,
 
 
 # ---------------------------------------------------------------------------
+# MoE with held experts (expert parallelism), every expert on the macro
+# ---------------------------------------------------------------------------
+
+
+def relu2_mlp_init(key, cfg: ModelConfig, d_ff: int, dtype=jnp.bfloat16):
+    """Non-gated MLP, down(relu(up(x))^2) (nemotron_h's experts)."""
+    ks = jax.random.split(key, 2)
+    p, a = {}, {}
+    p["w_up"], a["w_up"] = _init_dense(ks[0], cfg.d_model, d_ff,
+                                       ("embed", "moe_ff"), dtype=dtype)
+    p["w_down"], a["w_down"] = _init_dense(ks[1], d_ff, cfg.d_model,
+                                           ("moe_ff", "embed"), dtype=dtype)
+    return p, a
+
+
+def relu2_mlp_apply(p: Params, x: Array, cfg: ModelConfig, path: str) -> Array:
+    h = _dense(x, p["w_up"], cfg, f"{path}/w_up")
+    return _dense(jnp.square(jax.nn.relu(h)), p["w_down"], cfg,
+                  f"{path}/w_down")
+
+
+# tap names (obs/taps.py) of the held-expert layers' row counts: the
+# (live row, held expert) pairs the router chose, and the rows the held
+# experts' CIM calls computed
+MOE_TAPS = ("moe_routed_rows", "moe_expert_rows")
+
+
+def held_moe_init(key, cfg: ModelConfig, dtype=jnp.bfloat16):
+    """Router over all ``cfg.router_experts`` experts; the ``n_experts``
+    held here stacked on a leading axis under "experts"; the shared
+    expert under "shared".  Experts are relu^2 MLPs (nemotron_h's)."""
+    ks = jax.random.split(key, 3)
+    p, a = {}, {}
+    p["router"], a["router"] = _init_dense(
+        ks[0], cfg.d_model, cfg.router_experts, ("embed", "experts_r"),
+        dtype=jnp.float32)
+    p["e_bias"] = jnp.zeros((cfg.router_experts,), jnp.float32)
+    a["e_bias"] = ("experts_r",)
+    keys = jax.random.split(ks[1], cfg.n_experts)
+    p["experts"] = jax.vmap(
+        lambda k: relu2_mlp_init(k, cfg, cfg.moe_d_ff, dtype)[0])(keys)
+    a["experts"] = {k: ("experts",) + v for k, v in
+                    relu2_mlp_init(ks[1], cfg, cfg.moe_d_ff, dtype)[1].items()}
+    if cfg.shared_expert_d_ff:
+        p["shared"], a["shared"] = relu2_mlp_init(
+            ks[2], cfg, cfg.shared_expert_d_ff, dtype)
+    return p, a
+
+
+@scoped("lm.moe")
+def _held_experts(p: Params, xf: Array, cfg: ModelConfig, path: str,
+                  live: Optional[Array]) -> Array:
+    """The held experts' part of the routed sum for rows xf (T, D).
+
+    The router is float32 at full precision, as the published code
+    computes it: sigmoid scores, the top ``cfg.top_k`` of scores plus the
+    choice bias ``e_bias``, gates taken from the plain scores, normalised
+    to sum 1 and scaled by ``cfg.moe_routed_scaling``.  Each held expert
+    computes every row, weighted by the row's gate (0 where the row did
+    not choose it): rows quantize apart, so routed rows are exact and no
+    row is dropped.  Under a tap collector that takes them it emits the
+    ``MOE_TAPS`` counts; ``live`` ((T,) bool) marks the rows whose
+    choices count."""
+    T = xf.shape[0]
+    logits = jnp.dot(xf.astype(jnp.float32), p["router"],
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits)
+    _, idx = jax.lax.top_k(scores + p["e_bias"], cfg.top_k)          # (T,K)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    w = w * cfg.moe_routed_scaling
+    held = cfg.moe_first_expert + jnp.arange(cfg.n_experts)
+    hit = idx[:, :, None] == held                                     # (T,K,E)
+    gate = jnp.sum(jnp.where(hit, w[:, :, None], 0.0), axis=1)        # (T,E)
+    if taps.active(MOE_TAPS[0]):
+        chose = jnp.any(hit, axis=1)
+        if live is not None:
+            chose = chose & live[:, None]
+        taps.emit(MOE_TAPS[0], jnp.sum(chose.astype(jnp.int32)))
+        taps.emit(MOE_TAPS[1], jnp.int32(T * cfg.n_experts))
+
+    def expert(acc, inp):
+        pe, g = inp
+        y = relu2_mlp_apply(pe, xf, cfg, f"{path}/experts")
+        return acc + y * g[:, None], None
+
+    y, _ = taps.scan(expert, jnp.zeros(xf.shape, jnp.float32),
+                     (p["experts"], gate.T))
+    return y
+
+
+def held_moe_apply(p: Params, x: Array, cfg: ModelConfig, path: str = "moe",
+                   live: Optional[Array] = None) -> Array:
+    """x (B, S, D) -> the held experts' part of the routed sum, summed
+    in float32, plus the shared expert (B, S, D) in x's dtype.
+    ``live`` ((B,) bool, one row a sequence: decode) marks the rows
+    whose choices the ``MOE_TAPS`` counts take."""
+    B, S, D = x.shape
+    xf = x.reshape(B * S, D)
+    y = _held_experts(p, xf, cfg, path, live).astype(x.dtype)
+    if "shared" in p:
+        with jax.named_scope("lm.moe_shared"):
+            y = y + relu2_mlp_apply(p["shared"], xf, cfg, f"{path}/shared")
+    return y.reshape(B, S, D)
+
+
+# ---------------------------------------------------------------------------
 # Mamba2 (SSD) -- chunked parallel scan for train/prefill, step for decode
 # ---------------------------------------------------------------------------
 
 
 def mamba2_init(key, cfg: ModelConfig, dtype=jnp.bfloat16):
-    D, DI, N, H = cfg.d_model, cfg.d_inner, cfg.ssm_state, cfg.ssm_heads
-    W = cfg.ssm_conv_width
+    D, DI, H = cfg.d_model, cfg.d_inner, cfg.ssm_heads
+    W, BC = cfg.ssm_conv_width, cfg.ssm_bc_dim
     ks = jax.random.split(key, 8)
     p, a = {}, {}
     # component projections (not one fused in_proj): each output dim is
@@ -799,7 +908,7 @@ def mamba2_init(key, cfg: ModelConfig, dtype=jnp.bfloat16):
                                      dtype=dtype)
     p["w_x"], a["w_x"] = _init_dense(ks[4], D, DI, ("embed", "ssm_inner"),
                                      dtype=dtype)
-    p["w_bc"], a["w_bc"] = _init_dense(ks[5], D, 2 * N, ("embed", "state"),
+    p["w_bc"], a["w_bc"] = _init_dense(ks[5], D, BC, ("embed", "state"),
                                        dtype=dtype)
     p["w_dt"], a["w_dt"] = _init_dense(ks[6], D, H, ("embed", "ssm_heads"),
                                        dtype=dtype)
@@ -807,11 +916,11 @@ def mamba2_init(key, cfg: ModelConfig, dtype=jnp.bfloat16):
     # sharded channel dim -> no resharding collective-permutes in the scan
     p["conv_x"] = (jax.random.normal(ks[1], (W, DI), jnp.float32) / W).astype(dtype)
     a["conv_x"] = ("conv", "ssm_inner")
-    p["conv_b"] = (jax.random.normal(ks[7], (W, 2 * N), jnp.float32) / W).astype(dtype)
+    p["conv_b"] = (jax.random.normal(ks[7], (W, BC), jnp.float32) / W).astype(dtype)
     a["conv_b"] = ("conv", "state")
     p["conv_bias_x"] = jnp.zeros((DI,), dtype)
     a["conv_bias_x"] = ("ssm_inner",)
-    p["conv_bias_b"] = jnp.zeros((2 * N,), dtype)
+    p["conv_bias_b"] = jnp.zeros((BC,), dtype)
     a["conv_bias_b"] = ("state",)
     p["A_log"] = jnp.log(jnp.linspace(1.0, 16.0, H).astype(jnp.float32))
     a["A_log"] = ("ssm_heads",)
@@ -868,9 +977,15 @@ def _mamba2_mixer(p: Params, proj, x: Array, cfg: ModelConfig, ssm_state,
     """mamba2 between its projections: the causal convs, the chunked SSD
     scan (or the single-step recurrence), the state update and the gate.
     Returns (y (B, S padded to a chunk multiple, DI), new_ssm_state,
-    new_conv_state)."""
+    new_conv_state).
+
+    With ``cfg.ssm_n_groups`` G > 1 the B and C streams hold G groups of
+    ``ssm_state`` channels, group g serving heads [g*H/G, (g+1)*H/G), and
+    the gated norm normalises each group's DI/G channels apart (mamba_ssm's
+    ``RMSNormGated`` with ``group_size = d_inner / n_groups``)."""
     B, S, D = x.shape
     DI, N, H, P = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads, cfg.ssm_head_dim
+    G = cfg.ssm_n_groups
     z, xc, BCc, dt_raw = (proj[n] for n in ("w_z", "w_x", "w_bc", "w_dt"))
     cs_x = cs_bc = None
     if conv_state is not None:
@@ -878,7 +993,7 @@ def _mamba2_mixer(p: Params, proj, x: Array, cfg: ModelConfig, ssm_state,
     xc, new_cx = _causal_conv(xc, p["conv_x"], p["conv_bias_x"], cs_x)
     BCc, new_cbc = _causal_conv(BCc, p["conv_b"], p["conv_bias_b"], cs_bc)
     new_conv = (new_cx, new_cbc)
-    Bc, Cc = jnp.split(BCc, [N], axis=-1)
+    Bc, Cc = jnp.split(BCc, [G * N], axis=-1)
     dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"])  # (B,S,H)
     A = -jnp.exp(p["A_log"])                                          # (H,)
 
@@ -897,58 +1012,85 @@ def _mamba2_mixer(p: Params, proj, x: Array, cfg: ModelConfig, ssm_state,
             S = S + pad
 
     xh = xc.reshape(B, S, H, P).astype(jnp.float32)
-    Bh = Bc.astype(jnp.float32)                                       # (B,S,N)
+    Bh = Bc.astype(jnp.float32)                                       # (B,S,GN)
     Ch = Cc.astype(jnp.float32)
+    ssd = partial(_ssd, decode=decode, chunk=cfg.ssm_chunk)
+    if G == 1:
+        y, new_state = ssd(xh, Bh, Ch, dt, A, ssm_state, p["D_skip"])
+    else:
+        # each group is a G=1 SSD over its own heads: vmap over groups
+        Hg = H // G
+        if ssm_state is None:
+            ssm_state = jnp.zeros((B, H, P, N), jnp.float32)
+        y, new_state = jax.vmap(ssd, in_axes=(2, 2, 2, 2, 0, 1, 0),
+                                out_axes=(2, 1))(
+            xh.reshape(B, S, G, Hg, P), Bh.reshape(B, S, G, N),
+            Ch.reshape(B, S, G, N), dt.reshape(B, S, G, Hg),
+            A.reshape(G, Hg), ssm_state.reshape(B, G, Hg, P, N),
+            p["D_skip"].reshape(G, Hg))
+        y = y.reshape(B, S, DI)
+        new_state = new_state.reshape(B, H, P, N)
 
+    y = y.astype(x.dtype) * jax.nn.silu(z)
+    if G == 1:
+        y = rms_norm(y, p["gate_norm"], cfg.norm_eps)
+    else:
+        y = rms_norm(y.reshape(B, S, G, DI // G),
+                     p["gate_norm"].reshape(G, DI // G),
+                     cfg.norm_eps).reshape(B, S, DI)
+    return y, new_state, new_conv
+
+
+def _ssd(xh, Bh, Ch, dt, A, ssm_state, D_skip, decode: bool, chunk: int):
+    """The selective-state recurrence of heads that share one B and C:
+    xh (B,S,H,P), Bh/Ch (B,S,N), dt (B,S,H), A/D_skip (H,), ssm_state
+    (B,H,P,N) or None (zeros).  Returns (y (B,S,H*P), new_state)."""
+    B, S, H, P = xh.shape
+    N = Bh.shape[-1]
     if decode:
         # single-step recurrence: state (B,H,P,N)
         a = jnp.exp(dt[:, 0] * A[None, :])                            # (B,H)
         dBx = jnp.einsum("bh,bn,bhp->bhpn", dt[:, 0], Bh[:, 0], xh[:, 0])
         new_state = ssm_state * a[..., None, None] + dBx
         y = jnp.einsum("bn,bhpn->bhp", Ch[:, 0], new_state)
-        y = y + p["D_skip"][None, :, None] * xh[:, 0]
-        y = y.reshape(B, 1, DI)
-    else:
-        Q = min(cfg.ssm_chunk, S)
-        assert S % Q == 0, (S, Q)
-        nc = S // Q
-        xb = xh.reshape(B, nc, Q, H, P)
-        Bb = Bh.reshape(B, nc, Q, N)
-        Cb = Ch.reshape(B, nc, Q, N)
-        dtb = dt.reshape(B, nc, Q, H)
-        a = dtb * A  # (B,nc,Q,H) log-decay
-        a_t = a.transpose(0, 1, 3, 2)                                 # (B,nc,H,Q)
-        Lmat = jnp.exp(_segsum(a_t))                                  # (B,nc,H,Q,Q)
-        # intra-chunk (diagonal) term
-        scores = jnp.einsum("bcqn,bckn->bcqk", Cb, Bb)                # (B,nc,Q,Q)
-        y_diag = jnp.einsum("bcqk,bchqk,bckh,bckhp->bcqhp",
-                            scores, Lmat, dtb, xb)
-        # decay from step q to end of chunk: sum_{i>q} a_i
-        a_cum = jnp.cumsum(a_t, axis=-1)                              # (B,nc,H,Q)
-        decay_to_end = jnp.exp(a_cum[..., -1:] - a_cum)               # (B,nc,H,Q)
-        states = jnp.einsum("bchq,bcqh,bcqn,bcqhp->bchpn",
-                            decay_to_end, dtb, Bb, xb)                # (B,nc,H,P,N)
-        chunk_decay = jnp.exp(a_cum[..., -1])                         # (B,nc,H)
+        y = y + D_skip[None, :, None] * xh[:, 0]
+        return y.reshape(B, 1, H * P), new_state
+    Q = min(chunk, S)
+    assert S % Q == 0, (S, Q)
+    nc = S // Q
+    xb = xh.reshape(B, nc, Q, H, P)
+    Bb = Bh.reshape(B, nc, Q, N)
+    Cb = Ch.reshape(B, nc, Q, N)
+    dtb = dt.reshape(B, nc, Q, H)
+    a = dtb * A  # (B,nc,Q,H) log-decay
+    a_t = a.transpose(0, 1, 3, 2)                                     # (B,nc,H,Q)
+    Lmat = jnp.exp(_segsum(a_t))                                      # (B,nc,H,Q,Q)
+    # intra-chunk (diagonal) term
+    scores = jnp.einsum("bcqn,bckn->bcqk", Cb, Bb)                    # (B,nc,Q,Q)
+    y_diag = jnp.einsum("bcqk,bchqk,bckh,bckhp->bcqhp",
+                        scores, Lmat, dtb, xb)
+    # decay from step q to end of chunk: sum_{i>q} a_i
+    a_cum = jnp.cumsum(a_t, axis=-1)                                  # (B,nc,H,Q)
+    decay_to_end = jnp.exp(a_cum[..., -1:] - a_cum)                   # (B,nc,H,Q)
+    states = jnp.einsum("bchq,bcqh,bcqn,bcqhp->bchpn",
+                        decay_to_end, dtb, Bb, xb)                    # (B,nc,H,P,N)
+    chunk_decay = jnp.exp(a_cum[..., -1])                             # (B,nc,H)
 
-        def scan_fn(h, inp):
-            st, dec = inp
-            h_new = h * dec[..., None, None] + st
-            return h_new, h
-        init = (ssm_state if ssm_state is not None
-                else jnp.zeros((B, H, P, N), jnp.float32))
-        new_state, h_prev = jax.lax.scan(
-            scan_fn,
-            init,
-            (states.transpose(1, 0, 2, 3, 4), chunk_decay.transpose(1, 0, 2)),
-        )
-        h_prev = h_prev.transpose(1, 0, 2, 3, 4)                      # (B,nc,H,P,N)
-        decay_from_start = jnp.exp(a_cum)                             # (B,nc,H,Q)
-        y_off = jnp.einsum("bcqn,bchq,bchpn->bcqhp",
-                           Cb, decay_from_start, h_prev)
-        y = (y_diag + y_off).reshape(B, S, H, P)
-        y = y + p["D_skip"][None, None, :, None] * xh
-        y = y.reshape(B, S, DI)
-
-    y = y.astype(x.dtype) * jax.nn.silu(z)
-    y = rms_norm(y, p["gate_norm"], cfg.norm_eps)
-    return y, new_state, new_conv
+    def scan_fn(h, inp):
+        st, dec = inp
+        h_new = h * dec[..., None, None] + st
+        return h_new, h
+    init = (ssm_state if ssm_state is not None
+            else jnp.zeros((B, H, P, N), jnp.float32))
+    new_state, h_prev = jax.lax.scan(
+        scan_fn,
+        init,
+        (states.transpose(1, 0, 2, 3, 4), chunk_decay.transpose(1, 0, 2)),
+    )
+    h_prev = h_prev.transpose(1, 0, 2, 3, 4)                          # (B,nc,H,P,N)
+    decay_from_start = jnp.exp(a_cum)                                 # (B,nc,H,Q)
+    y_off = jnp.einsum("bcqn,bchq,bchpn->bcqhp",
+                       Cb, decay_from_start, h_prev)
+    y = (y_diag + y_off).reshape(B, S, H, P)
+    y = y + D_skip[None, None, :, None] * xh
+    return y.reshape(B, S, H * P), new_state

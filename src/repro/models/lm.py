@@ -24,7 +24,10 @@ Families:
   moe      : [attn, moe] x L
   ssm      : [mamba2] x L
   hybrid   : groups of `period` mamba layers + ONE shared attn+mlp block
-             (zamba2 -- weight co-location showcase, see DESIGN.md)
+             (zamba2 -- weight co-location showcase, see DESIGN.md); or,
+             with ``cfg.layer_types``, one pre-norm residual block per
+             character -- "M" mamba2, "E" held-expert MoE, "*" attention
+             (nemotron_h) -- each kind stacked on its own leading axis
   vlm      : dense backbone + frontend patch-embedding stub, prefix-LM
   audio    : dense backbone over codec-token frames (frontend stub)
 """
@@ -42,7 +45,7 @@ from ..obs import taps
 from ..obs.scopes import scoped
 from ..obs.trace import span
 from . import layers as L
-from .config import ModelConfig
+from .config import LAYER_KINDS, ModelConfig
 
 Array = jax.Array
 Params = Dict[str, Any]
@@ -89,6 +92,32 @@ def _block_init(cfg: ModelConfig, dtype):
     return init
 
 
+def _kind_block_init(cfg: ModelConfig, kind: str, dtype):
+    """Per-layer init of one ``layer_types`` kind: its mixer and norm."""
+    mixer = {"M": ("mamba", L.mamba2_init), "E": ("moe", L.held_moe_init),
+             "*": ("attn", L.attention_init)}[kind]
+
+    def init(key):
+        p, a = {}, {}
+        p[mixer[0]], a[mixer[0]] = mixer[1](key, cfg, dtype)
+        p["ln1"], a["ln1"] = jnp.zeros((cfg.d_model,), dtype), ("embed",)
+        return p, a
+
+    return init
+
+
+def _pattern_init(cfg: ModelConfig, key, dtype):
+    """params["layers"] of a ``layer_types`` stack: {kind: that kind's
+    layers stacked in order}; a layer's index there is its rank among the
+    layers of its kind, as in the cache."""
+    p, a = {}, {}
+    for kind, k in zip(LAYER_KINDS, jax.random.split(key, len(LAYER_KINDS))):
+        if cfg.layer_count(kind):
+            p[kind], a[kind] = _stacked_init(_kind_block_init(cfg, kind, dtype),
+                                             k, cfg.layer_count(kind))
+    return p, a
+
+
 def _shared_block_init(cfg: ModelConfig, key, dtype):
     ks = jax.random.split(key, 2)
     p, a = {}, {}
@@ -111,9 +140,13 @@ def init(key, cfg: ModelConfig, pack_cim: bool = False) -> Tuple[Params, Dict]:
     p["embed"] = (jax.random.normal(k_emb, (cfg.vocab_size, cfg.d_model),
                                     jnp.float32) * 0.02).astype(dtype)
     a["embed"] = ("vocab", "head_embed")
-    blk_init = _block_init(cfg, dtype)
-    p["layers"], a["layers"] = _stacked_init(blk_init, k_layers, cfg.n_layers)
-    if cfg.family == "hybrid":
+    if cfg.layer_types:
+        p["layers"], a["layers"] = _pattern_init(cfg, k_layers, dtype)
+    else:
+        blk_init = _block_init(cfg, dtype)
+        p["layers"], a["layers"] = _stacked_init(blk_init, k_layers,
+                                                 cfg.n_layers)
+    if cfg.family == "hybrid" and not cfg.layer_types:
         p["shared"], a["shared"] = _shared_block_init(cfg, k_shared, dtype)
     p["ln_f"], a["ln_f"] = jnp.zeros((cfg.d_model,), dtype), ("embed",)
     if not cfg.tie_embeddings:
@@ -136,7 +169,11 @@ _CIM_PACKABLE = frozenset({
     "wq", "wk", "wv", "wo",                      # attention
     "w1", "w2", "w3",                            # (shared-)MLP
     "w_z", "w_x", "w_bc", "w_dt", "out_proj",    # mamba2 projections
+    "w_up", "w_down",                            # relu^2 (held) experts
 })
+# Keys of stacked layers, dropped from plan paths: "layers", and a
+# layer_types stack's kinds under it.
+_STACK_KEYS = frozenset(("layers",) + LAYER_KINDS)
 
 
 def _walk_packable(tree, visit, path=()):
@@ -144,14 +181,16 @@ def _walk_packable(tree, visit, path=()):
     _dense-consumed projection leaf.  ``plan_path`` is the deployment-plan
     path convention: tree keys joined with "/", the scanned-stack key
     "layers" dropped (one entry covers every depth of a scanned stack) --
-    e.g. "attn/wq", "moe/shared/w1", "shared/mlp/w2", "mamba/out_proj".
+    e.g. "attn/wq", "moe/shared/w1", "shared/mlp/w2", "mamba/out_proj"
+    (a layer_types stack's kind keys are dropped the same way).
     MoE expert tensors reuse the w1/w2/w3 names but feed einsums, not
     _dense, so the level directly under "moe" is skipped (the shared
-    expert under moe/shared IS packable).
+    expert under moe/shared IS packable, as are the held experts under
+    moe/experts, which run through _dense).
     """
     out = {}
     for k, v in tree.items():
-        sub = path if k == "layers" else path + (k,)
+        sub = path if k in _STACK_KEYS else path + (k,)
         if isinstance(v, dict):
             out[k] = _walk_packable(v, visit, sub)
         elif k in _CIM_PACKABLE and not (len(path) >= 1 and path[-1] == "moe"):
@@ -190,11 +229,12 @@ def _pack_single(path: str, v, cfg: ModelConfig):
     eng = L.cim_engine(cfg, path)
     if eng.fidelity == "float":              # plan keeps this site off-macro
         return v
-    if v.ndim == 2:                          # (K, N): shared-block weights
-        return eng.pack(v)
-    if v.ndim == 3:                          # (layers, K, N): scanned stack
-        return jax.vmap(eng.pack)(v)
-    return v                                 # MoE expert tensors etc.
+    # (K, N) shared-block weights; (layers, K, N) a scanned stack;
+    # (layers, experts, K, N) held experts
+    pack = eng.pack
+    for _ in range(v.ndim - 2):
+        pack = jax.vmap(pack)
+    return pack(v)
 
 
 def _pack_tree(tree: Params, cfg: ModelConfig, path=()) -> Params:
@@ -235,7 +275,7 @@ def _pack_tree(tree: Params, cfg: ModelConfig, path=()) -> Params:
         if k in consumed:
             del out[k]
             continue
-        sub = path if k == "layers" else path + (k,)
+        sub = path if k in _STACK_KEYS else path + (k,)
         if isinstance(v, dict):
             out[k] = _pack_tree(v, cfg, sub)
         elif packable(k):
@@ -386,7 +426,11 @@ def _slice_layers(tree, lo: int, hi: int):
 
 def _ssm_stack(params, cfg: ModelConfig, x, positions, remat,
                period_blocks=True):
-    """Mamba2 stack; for 'hybrid', one SHARED attn block every `period`."""
+    """Mamba2 stack; for 'hybrid', one SHARED attn block every `period`,
+    or the ``layer_types`` stack."""
+    if cfg.layer_types:
+        return _pattern_stack(params, cfg, x, positions)[0]
+
     def body(x, blk):
         h, _ = L.mamba2_apply(
             blk["mamba"], L.rms_norm(x, blk["ln1"], cfg.norm_eps), cfg)
@@ -419,26 +463,22 @@ def _ssm_stack(params, cfg: ModelConfig, x, positions, remat,
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_seq: int,
-               dtype=jnp.bfloat16) -> Dict[str, Array]:
-    """Allocate the decode cache for `batch` sequences of up to `max_seq`.
+               dtype=None) -> Dict[str, Array]:
+    """Allocate the decode cache for `batch` sequences of up to `max_seq`,
+    KV and conv state in ``dtype`` (default: ``cfg.act_dtype``).
 
     ``pos`` is a per-slot ``(batch,)`` vector: every sequence in the pool
     tracks its own write position, so slots at different depths (continuous
     batching, launch/scheduler.py) share one cache and one compiled step.
     """
     c: Dict[str, Any] = {"pos": jnp.zeros((batch,), jnp.int32)}
+    dtype = jnp.dtype(cfg.act_dtype) if dtype is None else dtype
     hkv, dh = cfg.padded_kv_heads, cfg.head_dim
-    if cfg.family in ("dense", "moe", "vlm", "audio"):
-        shape = (cfg.n_layers, batch, max_seq, hkv, dh)
+    if cfg.attn_layers:
+        shape = (cfg.attn_layers, batch, max_seq, hkv, dh)
         c["k"] = jnp.zeros(shape, dtype)
         c["v"] = jnp.zeros(shape, dtype)
-    if cfg.family in ("ssm", "hybrid"):
-        H, P, N = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
-        W = cfg.ssm_conv_width
-        c["ssm"] = jnp.zeros((cfg.n_layers, batch, H, P, N), jnp.float32)
-        c["conv_x"] = jnp.zeros((cfg.n_layers, batch, W - 1, cfg.d_inner),
-                                dtype)
-        c["conv_bc"] = jnp.zeros((cfg.n_layers, batch, W - 1, 2 * N), dtype)
+    c.update(_state_cache(cfg, batch, dtype))
     if cfg.family == "hybrid" and cfg.shared_attn_period:
         n_inv = cfg.n_layers // cfg.shared_attn_period
         c["shared_k"] = jnp.zeros((n_inv, batch, max_seq, hkv, dh), dtype)
@@ -446,37 +486,45 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int,
     return c
 
 
+def _state_cache(cfg: ModelConfig, batch: int, dtype) -> Dict[str, Array]:
+    """Per-slot SSM and conv state of the layers that keep it."""
+    c: Dict[str, Any] = {}
+    if cfg.ssm_layers:
+        H, P, N = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
+        W, n = cfg.ssm_conv_width, cfg.ssm_layers
+        c["ssm"] = jnp.zeros((n, batch, H, P, N), jnp.float32)
+        c["conv_x"] = jnp.zeros((n, batch, W - 1, cfg.d_inner), dtype)
+        c["conv_bc"] = jnp.zeros((n, batch, W - 1, cfg.ssm_bc_dim), dtype)
+    return c
+
+
 def init_paged_cache(cfg: ModelConfig, batch: int, n_blocks: int,
                      block_size: int, n_tbl: int,
-                     dtype=jnp.bfloat16) -> Dict[str, Array]:
+                     dtype=None) -> Dict[str, Array]:
     """Allocate the PAGED decode cache: per-layer global KV block pools
     plus a per-slot block table.
 
     KV memory no longer scales with ``batch * max_seq`` -- the pools are
-    ``(n_layers, n_blocks, block_size, hkv, dh)`` shared by every slot,
+    ``(attention layers, n_blocks, block_size, hkv, dh)`` shared by every slot,
     and slot b's logical row p resolves through ``table[b, p //
     block_size]``.  The presence of the ``"table"`` key is what flips
     prefill/decode_step/verify_step (and the slot helpers) into paged
     mode; SSM/conv state stays per-slot dense (it is O(1) per slot, not
-    O(max_seq)).  Block 0 is reserved as the trash block by the
+    O(max_seq)).  KV and conv state are kept in ``dtype`` (default:
+    ``cfg.act_dtype``).  Block 0 is reserved as the trash block by the
     allocator (launch/scheduler.py); an all-zero table row -- the reset
     state -- therefore points every position at garbage no live slot
     reads.
     """
     c: Dict[str, Any] = {"pos": jnp.zeros((batch,), jnp.int32),
                          "table": jnp.zeros((batch, n_tbl), jnp.int32)}
+    dtype = jnp.dtype(cfg.act_dtype) if dtype is None else dtype
     hkv, dh = cfg.padded_kv_heads, cfg.head_dim
-    if cfg.family in ("dense", "moe", "vlm", "audio"):
-        shape = (cfg.n_layers, n_blocks, block_size, hkv, dh)
+    if cfg.attn_layers:
+        shape = (cfg.attn_layers, n_blocks, block_size, hkv, dh)
         c["k"] = jnp.zeros(shape, dtype)
         c["v"] = jnp.zeros(shape, dtype)
-    if cfg.family in ("ssm", "hybrid"):
-        H, P, N = cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state
-        W = cfg.ssm_conv_width
-        c["ssm"] = jnp.zeros((cfg.n_layers, batch, H, P, N), jnp.float32)
-        c["conv_x"] = jnp.zeros((cfg.n_layers, batch, W - 1, cfg.d_inner),
-                                dtype)
-        c["conv_bc"] = jnp.zeros((cfg.n_layers, batch, W - 1, 2 * N), dtype)
+    c.update(_state_cache(cfg, batch, dtype))
     if cfg.family == "hybrid" and cfg.shared_attn_period:
         n_inv = cfg.n_layers // cfg.shared_attn_period
         c["shared_k"] = jnp.zeros((n_inv, n_blocks, block_size, hkv, dh),
@@ -686,7 +734,8 @@ def decode_step(params, cfg: ModelConfig, token: Array, cache: Dict,
         old = {k: cache[k] for k in ("ssm", "conv_x", "conv_bc")
                if k in cache}
         x, cache = _ssm_stack_cached(params, cfg, x, positions, cache,
-                                     decode=True, write_mask=wmask)
+                                     decode=True, write_mask=wmask,
+                                     live=live)
         if wmask is not None:
             m = wmask[None, :, None, None]
             for k, v in old.items():
@@ -764,13 +813,17 @@ def verify_step(params, cfg: ModelConfig, tokens: Array, cache: Dict,
 
 def _ssm_stack_cached(params, cfg: ModelConfig, x, positions, cache,
                       decode: bool, chunked: bool = False,
-                      write_mask=None):
+                      write_mask=None, live=None):
     """``chunked=True`` is the mid-prompt prefill mode: conv + SSM state
     carry across chunk calls (a fresh prefill passes zero conv state via
     None -- bit-identical to explicit zeros) and the hybrid shared-attn
     block writes at the slot's current ``pos`` instead of 0.
     ``write_mask`` guards the paged shared-attn KV write for non-live
-    rows (decode_step holds their SSM/conv state itself)."""
+    rows (decode_step holds their SSM/conv state itself); ``live``
+    marks the decode rows whose expert choices count."""
+    if cfg.layer_types:
+        return _pattern_stack(params, cfg, x, positions, cache, decode,
+                              chunked, write_mask, live)
     pos = cache["pos"]
     tbl = cache.get("table")
 
@@ -824,6 +877,59 @@ def _ssm_stack_cached(params, cfg: ModelConfig, x, positions, cache,
     cache["conv_bc"] = jnp.concatenate(new_cbc, axis=0)
     cache["shared_k"] = jnp.stack(new_k, axis=0)
     cache["shared_v"] = jnp.stack(new_v, axis=0)
+    return x, cache
+
+
+def _pattern_stack(params, cfg: ModelConfig, x, positions, cache=None,
+                   decode: bool = False, chunked: bool = False,
+                   write_mask=None, live=None):
+    """The ``layer_types`` stack: x + mixer(rms_norm(x)) per character,
+    unrolled (no kind repeats back to back in nemotron_h's pattern, so
+    no run is worth a scan).  A layer reads its params and cache entries
+    at its rank among the layers of its kind.  The residual stream is
+    kept in ``cfg.act_dtype`` (float32).  ``cache`` None is the training
+    forward; otherwise modes, masks and the state returned are
+    ``_ssm_stack_cached``'s.  Returns (x, cache)."""
+    layers, rank = params["layers"], dict.fromkeys(LAYER_KINDS, 0)
+    x = x.astype(cfg.act_dtype)
+    if cache is not None:
+        cache = dict(cache)
+        pos, tbl = cache["pos"], cache.get("table")
+        cache_pos = pos if (decode or chunked) else jnp.zeros_like(pos)
+        new = {k: [] for k in ("ssm", "conv_x", "conv_bc", "k", "v")}
+    for kind in cfg.layer_types:
+        r = rank[kind]
+        rank[kind] += 1
+        blk = jax.tree.map(lambda v: v[r], layers[kind])
+        h = L.rms_norm(x, blk["ln1"], cfg.norm_eps)
+        if kind == "M":
+            st = conv = None
+            if cache is not None:
+                st = cache["ssm"][r]
+                if decode or chunked:
+                    conv = (cache["conv_x"][r], cache["conv_bc"][r])
+            h, (st, (cx, cbc)) = L.mamba2_apply(
+                blk["mamba"], h, cfg, ssm_state=st, conv_state=conv,
+                decode=decode)
+            if cache is not None:
+                new["ssm"].append(st)
+                new["conv_x"].append(cx)
+                new["conv_bc"].append(cbc)
+        elif kind == "*":
+            kv = None if cache is None else (cache["k"][r], cache["v"][r])
+            h, kv = L.attention_apply(
+                blk["attn"], h, cfg, positions, jnp.bool_(False), kv_cache=kv,
+                cache_pos=None if cache is None else cache_pos,
+                block_table=None if cache is None else tbl,
+                write_mask=write_mask)
+            if cache is not None:
+                new["k"].append(kv[0])
+                new["v"].append(kv[1])
+        else:
+            h = L.held_moe_apply(blk["moe"], h, cfg, live=live)
+        x = x + h
+    if cache is not None:
+        cache.update({k: jnp.stack(v) for k, v in new.items() if v})
     return x, cache
 
 

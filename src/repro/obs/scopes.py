@@ -16,7 +16,8 @@ The serve loop's scopes:
   prefill (chunk) and speculative verify, norms and residuals included;
 * ``lm.embed``, ``lm.cim`` (every CIM projection), ``lm.attn`` (the
   attention core with its cache write and read), ``lm.ssm`` (the mamba2
-  mixer), ``lm.head``.
+  mixer), ``lm.moe`` (a held-expert layer's router, its held experts and
+  their gated sum), ``lm.moe_shared`` (its shared expert), ``lm.head``.
 """
 from __future__ import annotations
 

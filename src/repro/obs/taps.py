@@ -33,37 +33,48 @@ avals, analysis/tracer.py).
 from __future__ import annotations
 
 import contextlib
-from typing import Dict, Iterator, List
+from typing import Dict, FrozenSet, Iterable, Iterator, List, Optional
 
 import jax
 import jax.numpy as jnp
 
-# stack of live collector frames (innermost last); trace-time only
+# stack of live collector frames (innermost last), and the names each
+# takes (None: every name); trace-time only
 _STACK: List[Dict[str, List[jax.Array]]] = []
+_NAMES: List[Optional[FrozenSet[str]]] = []
 
 
-def active() -> bool:
-    """True while some ``collect()`` frame is open (trace-time check)."""
-    return bool(_STACK)
+def active(name: Optional[str] = None) -> bool:
+    """True while some ``collect()`` frame is open (trace-time check)
+    and, given ``name``, the innermost one takes it."""
+    return bool(_STACK) and (name is None or _NAMES[-1] is None
+                             or name in _NAMES[-1])
 
 
 def emit(name: str, value) -> None:
     """Record ``value`` under ``name`` in the innermost collector.
     No-op (and no tracing of ``value``'s producers happens at the call
-    site -- guard any extra computation with ``active()``) otherwise."""
-    if _STACK:
+    site -- guard any extra computation with ``active(name)``) where no
+    open frame takes ``name``."""
+    if active(name):
         _STACK[-1].setdefault(name, []).append(jnp.asarray(value))
 
 
 @contextlib.contextmanager
-def collect() -> Iterator[Dict[str, List[jax.Array]]]:
-    """Open a collector frame; yields the frame dict (name -> values)."""
+def collect(names: Optional[Iterable[str]] = None
+            ) -> Iterator[Dict[str, List[jax.Array]]]:
+    """Open a collector frame; yields the frame dict (name -> values).
+    ``names`` limits what it takes; by default it takes what the
+    enclosing frame takes (the scan and switch relays), or every name."""
     frame: Dict[str, List[jax.Array]] = {}
+    _NAMES.append(frozenset(names) if names is not None
+                  else (_NAMES[-1] if _NAMES else None))
     _STACK.append(frame)
     try:
         yield frame
     finally:
         _STACK.pop()
+        _NAMES.pop()
 
 
 def drain_sum(frame: Dict[str, List[jax.Array]], name: str,
