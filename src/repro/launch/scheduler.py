@@ -150,10 +150,17 @@ class ServeReport:
     block_size: int = 0
     n_tbl: int = 0
     # held-expert layers, over every MoE layer of every decode step and
-    # prefill chunk: (live row, held expert) pairs the router chose, and
-    # rows the held experts' CIM calls computed; 0 without such layers
+    # prefill chunk: (live row, held expert) pairs the router chose, rows
+    # the held experts' CIM calls computed, and held-expert calls taken
+    # (of n_experts a layer and pass); 0 without such layers
     moe_routed_rows: int = 0
     moe_expert_rows: int = 0
+    moe_expert_calls: int = 0
+
+    def take_moe_counts(self, carry) -> None:
+        """Take the held-expert counts (``MOE_TAPS``) from a loop's carry."""
+        for k in MOE_TAPS:
+            setattr(self, k, int(carry[k]))
 
     @property
     def total_tokens(self) -> int:
@@ -924,8 +931,7 @@ class ContinuousBatchingScheduler:
             if self.paged is not None:
                 out.update(n_pf=c["n_pf"], peak_blocks=c["peak_blocks"])
             if self._moe:
-                out.update(moe_routed=c["moe_routed"],
-                           moe_computed=c["moe_computed"])
+                out.update({k: c[k] for k in MOE_TAPS})
             if self.obs is not None:
                 out["obs"] = c["obs"]
             return out
@@ -1095,7 +1101,7 @@ class ContinuousBatchingScheduler:
                 req_tables=jnp.zeros((n_queue, self.paged.n_tbl),
                                      jnp.int32))
         if self._moe:
-            carry.update(moe_routed=_i32(0), moe_computed=_i32(0))
+            carry.update({k: _i32(0) for k in MOE_TAPS})
         if self.obs is not None and with_obs:
             carry["obs"] = obs_rings.init_obs_state(self.obs)
         return carry
@@ -1112,13 +1118,10 @@ class ContinuousBatchingScheduler:
         return contextlib.nullcontext({})
 
     def _moe_counts(self, c, frame) -> Dict[str, jax.Array]:
-        """The carry's held-expert row counts plus what a pass emitted."""
+        """The carry's held-expert counts plus what a pass emitted."""
         if not self._moe:
             return {}
-        return dict(
-            moe_routed=c["moe_routed"] + taps.drain_sum(frame, MOE_TAPS[0]),
-            moe_computed=c["moe_computed"] + taps.drain_sum(frame,
-                                                            MOE_TAPS[1]))
+        return {k: c[k] + taps.drain_sum(frame, k) for k in MOE_TAPS}
 
     # -- host-side staging ---------------------------------------------
 
@@ -1294,8 +1297,7 @@ class ContinuousBatchingScheduler:
                              peak_blocks=int(res.get("peak_blocks", 0)),
                              n_iter=n_iter, **self._attn_geometry())
         if self._moe:
-            report.moe_routed_rows = int(res["moe_routed"])
-            report.moe_expert_rows = int(res["moe_computed"])
+            report.take_moe_counts(res)
         if self.obs is not None:
             report.obs = obs_rings.harvest_obs(
                 self.obs, jax.device_get(res["obs"]), n_iter=n_iter,
@@ -1356,8 +1358,7 @@ class ContinuousBatchingScheduler:
             peak_blocks=int(c.get("peak_blocks", 0)), n_iter=len(branches),
             **self._attn_geometry())
         if self._moe:
-            report.moe_routed_rows = int(c["moe_routed"])
-            report.moe_expert_rows = int(c["moe_computed"])
+            report.take_moe_counts(c)
         if self.obs is not None:
             # the instrumented runner's carry keeps the rings inline
             # (the host round-trips it), so harvest reads them directly

@@ -26,6 +26,7 @@ import numpy as np
 from ..core.ccim import DEFAULT_CONFIG
 from ..core.engine import (CimEngine, FusedPackedCimWeights,
                            PackedCimWeights)
+from ..kernels.ccim_matmul.kernel import SKINNY_SUBLANE
 from ..kernels.paged_attn import ops as paged_attn_ops
 from ..obs import taps
 from ..obs.scopes import scoped
@@ -806,10 +807,10 @@ def relu2_mlp_apply(p: Params, x: Array, cfg: ModelConfig, path: str) -> Array:
                   f"{path}/w_down")
 
 
-# tap names (obs/taps.py) of the held-expert layers' row counts: the
-# (live row, held expert) pairs the router chose, and the rows the held
-# experts' CIM calls computed
-MOE_TAPS = ("moe_routed_rows", "moe_expert_rows")
+# tap names (obs/taps.py) of the held-expert layers' counts: the (live
+# row, held expert) pairs the router chose, the rows the held experts'
+# CIM calls computed, and the held-expert calls taken
+MOE_TAPS = ("moe_routed_rows", "moe_expert_rows", "moe_expert_calls")
 
 
 def held_moe_init(key, cfg: ModelConfig, dtype=jnp.bfloat16):
@@ -834,21 +835,15 @@ def held_moe_init(key, cfg: ModelConfig, dtype=jnp.bfloat16):
     return p, a
 
 
-@scoped("lm.moe")
-def _held_experts(p: Params, xf: Array, cfg: ModelConfig, path: str,
-                  live: Optional[Array]) -> Array:
-    """The held experts' part of the routed sum for rows xf (T, D).
+def held_moe_route(p: Params, xf: Array, cfg: ModelConfig
+                   ) -> Tuple[Array, Array]:
+    """Rows xf (T, D) -> each row's gate on each held expert, 0 where it
+    did not choose it, and whether it chose it: (T, n_experts) each.
 
     The router is float32 at full precision, as the published code
     computes it: sigmoid scores, the top ``cfg.top_k`` of scores plus the
     choice bias ``e_bias``, gates taken from the plain scores, normalised
-    to sum 1 and scaled by ``cfg.moe_routed_scaling``.  Each held expert
-    computes every row, weighted by the row's gate (0 where the row did
-    not choose it): rows quantize apart, so routed rows are exact and no
-    row is dropped.  Under a tap collector that takes them it emits the
-    ``MOE_TAPS`` counts; ``live`` ((T,) bool) marks the rows whose
-    choices count."""
-    T = xf.shape[0]
+    to sum 1 and scaled by ``cfg.moe_routed_scaling``."""
     logits = jnp.dot(xf.astype(jnp.float32), p["router"],
                      precision=jax.lax.Precision.HIGHEST)
     scores = jax.nn.sigmoid(logits)
@@ -858,21 +853,74 @@ def _held_experts(p: Params, xf: Array, cfg: ModelConfig, path: str,
     w = w * cfg.moe_routed_scaling
     held = cfg.moe_first_expert + jnp.arange(cfg.n_experts)
     hit = idx[:, :, None] == held                                     # (T,K,E)
-    gate = jnp.sum(jnp.where(hit, w[:, :, None], 0.0), axis=1)        # (T,E)
+    gate = jnp.sum(jnp.where(hit, w[:, :, None], 0.0), axis=1)
+    return gate, jnp.any(hit, axis=1)
+
+
+@scoped("lm.moe")
+def _held_experts(p: Params, xf: Array, cfg: ModelConfig, path: str,
+                  live: Optional[Array]) -> Array:
+    """The held experts' part of the routed sum for rows xf (T, D),
+    routed by ``held_moe_route``.  ``live`` ((T,) bool) marks the rows
+    whose choices count.
+
+    Each held expert computes by n, the live rows that chose it: nothing
+    where n is 0; those rows gathered into a buffer of min(T,
+    ``SKINNY_SUBLANE``) rows, the skinny kernel's route, where they fit;
+    else every row, weighted by its gate (0 where the row did not choose
+    the expert).  Rows quantize apart, so a routed row's output is the
+    same in any buffer, and no row is dropped.  With analog noise on
+    (``cfg.cim_noise_seed``), whose draws depend on the row count, every
+    expert computes every row.  Under a tap collector that takes them it
+    emits the ``MOE_TAPS`` counts."""
+    T = xf.shape[0]
+    gate, chose = held_moe_route(p, xf, cfg)
+    if live is not None:
+        chose = chose & live[:, None]
+    n = jnp.sum(chose, axis=0, dtype=jnp.int32)                       # (E,)
+
+    cap = min(T, SKINNY_SUBLANE)
+    experts = p["experts"]
+
+    def weights(e):
+        return jax.tree.map(
+            lambda v: jax.lax.dynamic_index_in_dim(v, e, keepdims=False),
+            experts)
+
+    def skip(acc, e, g, sel):
+        return acc
+
+    def every_row(acc, e, g, sel):
+        y = relu2_mlp_apply(weights(e), xf, cfg, f"{path}/experts")
+        return acc + y * g[:, None]
+
+    def routed(acc, e, g, sel):
+        rows = jnp.nonzero(sel, size=cap, fill_value=T)[0]
+        xg = jnp.take(xf, rows, axis=0, mode="fill", fill_value=0)
+        y = relu2_mlp_apply(weights(e), xg, cfg, f"{path}/experts")
+        # the routed rows' outputs in place, 0 elsewhere: every_row's sum
+        y = jnp.zeros((T,) + y.shape[1:], y.dtype).at[rows].set(y,
+                                                                mode="drop")
+        return acc + y * g[:, None]
+
+    # a buffer of T rows is a pass over every row
+    branches, rows_of = (((skip, every_row), (0, T)) if cap == T else
+                         ((skip, routed, every_row), (0, cap, T)))
+    if cfg.cim_noise_seed is not None:
+        kind = jnp.full(n.shape, len(branches) - 1)
+    else:
+        kind = jnp.where(n == 0, 0,
+                         jnp.where(n <= cap, 1, len(branches) - 1))
     if taps.active(MOE_TAPS[0]):
-        chose = jnp.any(hit, axis=1)
-        if live is not None:
-            chose = chose & live[:, None]
-        taps.emit(MOE_TAPS[0], jnp.sum(chose.astype(jnp.int32)))
-        taps.emit(MOE_TAPS[1], jnp.int32(T * cfg.n_experts))
+        taps.emit(MOE_TAPS[0], jnp.sum(n))
+        taps.emit(MOE_TAPS[1], jnp.sum(jnp.asarray(rows_of, jnp.int32)[kind]))
+        taps.emit(MOE_TAPS[2], jnp.sum(kind > 0, dtype=jnp.int32))
 
     def expert(acc, inp):
-        pe, g = inp
-        y = relu2_mlp_apply(pe, xf, cfg, f"{path}/experts")
-        return acc + y * g[:, None], None
+        return taps.switch(inp[1], branches, acc, inp[0], *inp[2:]), None
 
     y, _ = taps.scan(expert, jnp.zeros(xf.shape, jnp.float32),
-                     (p["experts"], gate.T))
+                     (jnp.arange(cfg.n_experts), kind, gate.T, chose.T))
     return y
 
 
@@ -881,7 +929,8 @@ def held_moe_apply(p: Params, x: Array, cfg: ModelConfig, path: str = "moe",
     """x (B, S, D) -> the held experts' part of the routed sum, summed
     in float32, plus the shared expert (B, S, D) in x's dtype.
     ``live`` ((B,) bool, one row a sequence: decode) marks the rows
-    whose choices the ``MOE_TAPS`` counts take."""
+    whose choices count: a held expert no live row chose computes
+    nothing."""
     B, S, D = x.shape
     xf = x.reshape(B * S, D)
     y = _held_experts(p, xf, cfg, path, live).astype(x.dtype)

@@ -114,23 +114,27 @@ def scan(body, init, xs):
 def switch(index, branches, *operands):
     """``jax.lax.switch`` that relays tap emissions across the boundary.
 
-    Every branch must emit the SAME set of tap names (lax.switch
-    requires structurally identical branch outputs) -- true for
-    homogeneous branch sets like the scheduler's draft-depth rungs,
-    where each rung runs the same kernels a different number of times.
-    Inactive -> plain lax.switch, same jaxpr.
+    ``lax.switch`` requires structurally identical branch outputs, so
+    every branch reports each name that any branch emits, 0 for a name
+    it does not (a branch that skips a kernel emits less than one that
+    runs it); the names are found by tracing each branch abstractly
+    first.  Inactive -> plain lax.switch, same jaxpr.
     """
     if not _STACK:
         return jax.lax.switch(index, branches, *operands)
 
-    def wrap(b):
+    def wrap(b, names=None):
         def b2(*ops):
             with collect() as frame:
                 out = b(*ops)
-            return out, {k: drain_sum(frame, k) for k in sorted(frame)}
+            return out, {k: drain_sum(frame, k)
+                         for k in sorted(frame if names is None else names)}
         return b2
 
-    out, tapped = jax.lax.switch(index, [wrap(b) for b in branches],
+    names = set()
+    for b in branches:
+        names.update(jax.eval_shape(wrap(b), *operands)[1])
+    out, tapped = jax.lax.switch(index, [wrap(b, names) for b in branches],
                                  *operands)
     for k, v in tapped.items():
         emit(k, v)

@@ -10,6 +10,10 @@ on the CPU with seeded weights from the benchmark's own ``weights.py``.
 * The expert share: the held-expert layer's outputs for all 16 shares
   of 8 held experts, the shared expert counted once, add up to the
   uncut reference layer's output over all 128.
+* Held-expert dispatch: an expert no live row chose computes nothing,
+  one with few routed rows computes them alone, one with many computes
+  every row; the output equals every expert over every row, gated, and
+  the ``MOE_TAPS`` counts are the branches taken.
 * ``ssm_n_groups`` = 1 leaves the mamba2 mixer bit-identical to the
   code before groups existed.
 """
@@ -30,6 +34,7 @@ from repro.launch.scheduler import ContinuousBatchingScheduler, Request
 from repro.models import layers as L
 from repro.models import lm
 from repro.models.config import ModelConfig
+from repro.obs import taps
 
 BENCH = os.path.join(os.path.dirname(__file__), "..", "bench")
 sys.path.insert(0, BENCH)
@@ -126,17 +131,19 @@ def test_served_logits_match_reference():
     # bfloat16 activations moves it by 0.27
     scale = want.std(axis=-1, keepdims=True)
     assert np.max(np.abs(got - want) / scale) < 1e-4
-    # every held expert computes every row of every pass (chunk rows and
-    # the two slots of each decode step, in each MoE layer); the router
-    # chose some of those (row, expert) pairs for live rows
+    # a held expert that some live row chose computes every row of its
+    # pass (16 chunk rows, or the two slots of a decode step: both fit
+    # the skinny route's buffer); one that none chose computes nothing
     n_moe = MODEL["layer_types"].count("E")
-    rows = CHUNK * rep.n_pf + 2 * rep.n_steps
-    assert rep.moe_expert_rows == rows * n_moe * MODEL["n_experts"]
+    calls = (rep.n_pf + rep.n_steps) * n_moe * MODEL["n_experts"]
+    assert 0 < rep.moe_expert_calls < calls
+    assert (2 * rep.moe_expert_calls <= rep.moe_expert_rows
+            <= CHUNK * rep.moe_expert_calls)
     assert 0 < rep.moe_routed_rows < rep.moe_expert_rows
     # the per-iteration runner steps the same body: the same counts
     inst = stepped()
-    assert (inst.moe_routed_rows, inst.moe_expert_rows) == (
-        rep.moe_routed_rows, rep.moe_expert_rows)
+    counts = lambda r: [getattr(r, k) for k in L.MOE_TAPS]
+    assert counts(inst) == counts(rep)
 
 
 def test_served_logits_on_the_macro_match_reference():
@@ -184,6 +191,98 @@ def test_expert_shares_add_up_to_the_uncut_layer(seed):
     total = total + shared
     # float32 sums in another order: 16 partial sums against one
     np.testing.assert_allclose(total, want, rtol=1e-5, atol=1e-5)
+
+
+def _held_layer(cfg, bias):
+    """Layer 0's held-expert layer of the tiny model, packed for the
+    macro, with ``bias`` added to the held experts' choice bias."""
+    model = dict(MODEL, dtype="bfloat16")
+    params = lm.pack_cim_params(weights.make_params(ARCH, model, SEED), cfg)
+    p = jax.tree.map(lambda v: v[0], params["layers"]["E"]["moe"])
+    held = slice(MODEL["moe_first_expert"],
+                 MODEL["moe_first_expert"] + MODEL["n_experts"])
+    return dict(p, e_bias=p["e_bias"].at[held].add(jnp.asarray(bias)))
+
+
+def _dense_over_held(p, x, cfg):
+    """Every held expert over every row, gated, summed in expert order,
+    plus the shared expert."""
+    xf = x.reshape(-1, x.shape[-1])
+    gate, _ = L.held_moe_route(p, xf, cfg)
+    acc = jnp.zeros(xf.shape, jnp.float32)
+    for e in range(cfg.n_experts):
+        pe = jax.tree.map(lambda v: v[e], p["experts"])
+        acc = acc + L.relu2_mlp_apply(pe, xf, cfg, "moe/experts") * gate[:, e:e + 1]
+    y = acc.astype(x.dtype) + L.relu2_mlp_apply(p["shared"], xf, cfg,
+                                                "moe/shared")
+    return y.reshape(x.shape)
+
+
+def _tapped_moe(p, x, cfg, live):
+    with taps.collect(L.MOE_TAPS) as frame:
+        y = L.held_moe_apply(p, x, cfg, live=live)
+    return y, [taps.drain_sum(frame, k) for k in L.MOE_TAPS]
+
+
+# expert 1 never chosen, expert 5 chosen by every row; the rest by a few
+BIAS = np.array([0.0, -1e9, 0.0, 0.0, 0.0, 1e9, 0.0, 0.0], np.float32)
+DISPATCH = {
+    # 48 decode slots, 12 of them dead: T 48 > the 32-row buffer
+    "decode": ((48, 1), np.arange(48) % 4 != 3),
+    # a prefill chunk of 64 rows, every row live
+    "prefill": ((1, 64), None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(DISPATCH))
+def test_held_expert_dispatch_is_bit_identical(case):
+    """Each held expert takes the branch its live routed rows call for,
+    and every live row's output equals (==) the dense-over-held sum."""
+    cfg = ModelConfig(**dict(MODEL, dtype="bfloat16"), cim_mode=True)
+    p = _held_layer(cfg, BIAS)
+    (B, S), live = DISPATCH[case]
+    x = jax.random.normal(jax.random.PRNGKey(7), (B, S, MODEL["d_model"]),
+                          jnp.float32)
+    live = None if live is None else jnp.asarray(live)
+    got, _ = jax.jit(lambda p, x, l: _tapped_moe(p, x, cfg, l))(p, x, live)
+    want = jax.jit(lambda p, x: _dense_over_held(p, x, cfg))(p, x)
+    keep = np.ones(B * S, bool) if live is None else np.repeat(live, S)
+    got, want = (np.asarray(a).reshape(B * S, -1)[keep] for a in (got, want))
+    assert np.array_equal(got, want)
+
+    # all three branches taken: no routed row, 1..32 of them, past 32
+    _, chose = L.held_moe_route(p, x.reshape(B * S, -1), cfg)
+    n = np.sum(np.asarray(chose) & keep[:, None], axis=0)
+    assert n[1] == 0 and n[5] == keep.sum() > 32
+    assert any(0 < v <= 32 for v in n)
+
+
+@pytest.mark.parametrize("noise", [None, 5])
+@pytest.mark.parametrize("case", sorted(DISPATCH))
+def test_held_expert_counts_match_the_branches(case, noise):
+    """``MOE_TAPS`` from the router's choices: routed pairs over live
+    rows; rows computed 0, 32 or T by branch; calls of experts any live
+    row chose.  With analog noise on every expert computes every row."""
+    cfg = ModelConfig(**dict(MODEL, dtype="bfloat16"), cim_mode=True,
+                      cim_noise_seed=noise)
+    p = _held_layer(cfg, BIAS)
+    (B, S), live = DISPATCH[case]
+    T, E = B * S, MODEL["n_experts"]
+    x = jax.random.normal(jax.random.PRNGKey(8), (B, S, MODEL["d_model"]),
+                          jnp.float32)
+    live = None if live is None else jnp.asarray(live)
+    _, got = jax.jit(lambda p, x, l: _tapped_moe(p, x, cfg, l))(p, x, live)
+
+    _, chose = L.held_moe_route(p, x.reshape(T, -1), cfg)
+    keep = np.ones(T, bool) if live is None else np.asarray(live)
+    n = np.sum(np.asarray(chose) & keep[:, None], axis=0)
+    if noise is None:
+        rows = sum(0 if v == 0 else 32 if v <= 32 else T for v in n)
+        calls = int(np.count_nonzero(n))
+    else:
+        rows, calls = T * E, E
+    assert [int(v) for v in got] == [int(n.sum()), rows, calls]
+    assert 0 < calls < E or noise is not None
 
 
 # SHA-256 of the mamba2 smoke mixer's outputs (fresh prefill; chunked with
