@@ -434,8 +434,9 @@ def _dcim_by_j(cfg: CCIMConfig) -> dict:
     return by_j
 
 
-def fold_dcim_planes(wq: Array, cfg: CCIMConfig = DEFAULT_CONFIG) -> list:
-    """Folded signed DCIM planes of integer weights, one per distinct j.
+def fold_dcim_planes(wq: Array, cfg: CCIMConfig = DEFAULT_CONFIG) -> Array:
+    """Folded signed DCIM planes of integer weights, (J, *wq.shape): one
+    per distinct j (J = 0 for an all-analog split).
 
     Plane_j = sign(w) * sum_{k in ks(j)} (2^(j+k)/dcim_lsb) * bit_k(|w|):
     the k-planes of w fold into a single weighted plane per x bit-plane
@@ -450,28 +451,38 @@ def fold_dcim_planes(wq: Array, cfg: CCIMConfig = DEFAULT_CONFIG) -> list:
         for k in ks:
             wsum = wsum + ((mw >> k) & 1) * ((1 << (j + k)) // cfg.dcim_lsb)
         planes.append(sw * wsum)
-    return planes
+    return jnp.stack(planes) if planes else jnp.zeros((0,) + wq.shape,
+                                                      sw.dtype)
 
 
 def fast_gemm_weight_ops(
-    wq: Array,                       # (C, L, N) ints in [-127, 127]
-    cfg: CCIMConfig = DEFAULT_CONFIG,
+    w: Array,                        # (K', N') ints, K' >= C*L, N' >= N
+    planes: Array,                   # (J, K', N') folded signed planes
+    k_dim: int,
+    n_dim: int,
+    acc_len: int,
 ) -> Tuple[Array, Array]:
-    """Weight-side operand prep for the fast GEMM (the weight-stationary
-    half of the dataflow -- computable ONCE per weight matrix).
+    """Weight-side operands of the fast GEMM, derived from integer tiles.
 
-    Returns (wf, w_planes): the float copy of the chunked weights and the
+    Returns (wf, w_planes): the chunked (C, L, N) float32 weights and the
     folded DCIM planes as ONE float32 (C, J*L, N) array -- the per-j
     planes concatenate along L, so the whole DCIM term is a single
     batched dot against the matching concatenated x planes (decode-shaped
     calls are launch-bound: one dot instead of J).  Planes carry the
     weight sign; their abs() is the magnitude plane the noisy path needs.
+
+    ``w`` and ``planes`` may be block-padded (the engine's int8 tiles):
+    rows past ``k_dim`` are zero and only the first C*L rows and ``n_dim``
+    columns are read.  Every value is a small integer, so the float32
+    conversion is exact.
     """
-    wf = wq.astype(jnp.float32)
-    planes = [p.astype(jnp.float32) for p in fold_dcim_planes(wq, cfg)]
-    C, L, N = wq.shape
-    w_pl = (jnp.concatenate(planes, axis=1) if planes
-            else jnp.zeros((C, 0, N), jnp.float32))
+    C = _pad_to_chunks(k_dim, acc_len)
+    kc = C * acc_len
+    J = planes.shape[0]
+    wf = w[:kc, :n_dim].astype(jnp.float32).reshape(C, acc_len, n_dim)
+    w_pl = jnp.transpose(
+        planes[:, :kc, :n_dim].astype(jnp.float32).reshape(
+            J, C, acc_len, n_dim), (1, 0, 2, 3)).reshape(C, J * acc_len, n_dim)
     return wf, w_pl
 
 
@@ -487,7 +498,9 @@ def hybrid_mac_fast_gemm(
     Bit-identical (including the noise draw) to summing hybrid_mac_fast's
     y8 over the (M,1,C,L) x (1,N,C,L) broadcast of the same operands.
     """
-    wf, w_pl = fast_gemm_weight_ops(wq, cfg)
+    C, L, N = wq.shape
+    w = wq.reshape(C * L, N)
+    wf, w_pl = fast_gemm_weight_ops(w, fold_dcim_planes(w, cfg), C * L, N, L)
     return hybrid_mac_fast_gemm_prepacked(xq, wf, w_pl, noise_key, cfg,
                                           noise_segments=noise_segments)
 

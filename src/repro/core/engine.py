@@ -4,31 +4,26 @@ The silicon macro is weight-stationary: quantized signed-magnitude weights
 are written into the SRAM array once and every subsequent MAC only streams
 activations.  The software stack mirrors that here -- ``pack_cim_weights``
 runs the full weight conditioning pipeline (per-channel SMF scale ->
-integer quantization -> sign/magnitude split -> folded MSB DCIM planes ->
-backend-specific layouts) ONE time, and ``packed_cim_matmul`` serves every
+integer quantization -> folded MSB DCIM planes -> block-padded int8
+tiles) ONE time, and ``packed_cim_matmul`` serves every
 later call with activation-only work.  Outputs are bit-identical to the
 unpacked path for every fidelity, including the noise draw: packing is a
 caching transform, not an approximation.
 
-Storage layouts carried by ``PackedCimWeights`` (all derived from the same
-integer weights, each feeding one consumer):
+The one stored layout of ``PackedCimWeights`` is the Pallas kernels' own:
 
-  sign/mag        raw SMF storage, (K, N) int8 -- the bit-cell contents;
-                  reconstructs w_q for the bit_true / broadcast / exact
-                  fidelities (cold paths).
-  gemm_w/gemm_planes
-                  (C, L, N) float32 chunked copies for the matmul-ized
-                  fast path (hybrid_mac_fast_gemm_prepacked): the float
-                  weight copy plus one folded signed DCIM plane per
-                  distinct x bit-plane j.
-  pallas_w/pallas_planes
-                  (Kp, Np) int8 block-padded tiles for the Pallas kernels
+  pallas_w        (Kp, Np) int8 integer weights, block-padded with zeros
                   (padding is M-independent by construction, see
-                  kernels.ccim_matmul.ops.pick_weight_blocks).
+                  kernels.ccim_matmul.ops.pick_weight_blocks);
+  pallas_planes   (n_j, Kp, Np) int8 folded signed DCIM planes, one per
+                  distinct activation bit-plane j.
 
-The trade is deliberate: ~4x the weight bytes of a bf16 matrix buys zero
-per-call weight conditioning -- the same area-for-latency trade the 2D
-capacitor array makes in silicon.
+At 1 + n_j bytes a weight (3 for the default top-3 split) it is what the
+TPU route reads.  Every other route derives its operands from the same
+tiles inside the graph, exactly (an int8 value converts exactly to
+float32, and the padding is zeros): the XLA fast GEMM takes the chunked
+float32 views (ccim.fast_gemm_weight_ops), and the cold fidelities and
+fault injection take the raw integers through ``wq()``.
 """
 from __future__ import annotations
 
@@ -44,13 +39,12 @@ from .ccim import (
     DEFAULT_CONFIG,
     MacroInstance,
     _dcim_by_j,
-    _pad_to_chunks,
     cim_matmul_int,
+    fast_gemm_weight_ops,
     fold_dcim_planes,
     hybrid_mac_fast_gemm_prepacked,
     quantize_smf,
     smf_scale,
-    split_sign_mag,
 )
 from ..resilience import faults as rfaults
 
@@ -68,11 +62,6 @@ class PackedCimWeights:
     """
 
     scale: Array                      # smf_scale output: (1, N) or scalar
-    sign: Array                       # (K, N) int8 in {-1, +1}
-    mag: Array                        # (K, N) int8 in [0, 127]
-    gemm_w: Array                     # (C, L, N) float32 chunked weights
-    gemm_planes: Array                # (C, J*L, N) float32 folded planes,
-                                      # L-concatenated over distinct j
     pallas_w: Array                   # (Kp, Np) int8, block-padded
     pallas_planes: Array              # (n_j, Kp, Np) int8 folded planes
     k_dim: int                        # static: logical K
@@ -82,8 +71,9 @@ class PackedCimWeights:
                                       # specific; use-time mismatch errors)
 
     def wq(self) -> Array:
-        """Reconstruct the raw integer SMF weights (cold-path fidelities)."""
-        return self.sign.astype(jnp.int32) * self.mag.astype(jnp.int32)
+        """The raw integer SMF weights (..., K, N) int32, cut from the
+        tiles (leading scan/expert axes pass through)."""
+        return self.pallas_w[..., :self.k_dim, :self.n_dim].astype(jnp.int32)
 
     def dequantized(self) -> Array:
         """float32 (K, N) dequantized weights (e.g. for the STE backward)."""
@@ -92,8 +82,7 @@ class PackedCimWeights:
 
 jax.tree_util.register_dataclass(
     PackedCimWeights,
-    data_fields=["scale", "sign", "mag", "gemm_w", "gemm_planes",
-                 "pallas_w", "pallas_planes"],
+    data_fields=["scale", "pallas_w", "pallas_planes"],
     meta_fields=["k_dim", "n_dim", "cfg"],
 )
 
@@ -167,38 +156,16 @@ def pack_quantized_cim_weights(
     from ..kernels.ccim_matmul.ops import pick_weight_blocks
 
     K, N = wq.shape
-    sign, mag = split_sign_mag(wq)
-    planes = fold_dcim_planes(wq, cfg)
-
-    # fast-GEMM layout: K padded to whole ADC conversions, chunked (C, L, N);
-    # folded planes concatenate along L into ONE (C, J*L, N) array so the
-    # whole DCIM term is a single batched dot at serve time
-    C = _pad_to_chunks(K, cfg.acc_len)
-    pad_k = C * cfg.acc_len - K
-    chunk = lambda v: jnp.pad(v, ((0, pad_k), (0, 0))).reshape(
-        C, cfg.acc_len, N)
-    gemm_w = chunk(wq).astype(jnp.float32)
-    gemm_planes = (jnp.concatenate([chunk(p).astype(jnp.float32)
-                                    for p in planes], axis=1) if planes
-                   else jnp.zeros((C, 0, N), jnp.float32))
-
-    # Pallas layout: block-padded once (M-independent by construction);
-    # the pad geometry follows the config's accumulate length, and an
-    # all-analog split (n_dcim_products=0) simply has zero folded planes
+    # block-padded once (M-independent by construction); the pad geometry
+    # follows the config's accumulate length, and an all-analog split
+    # (n_dcim_products=0) simply has zero folded planes
     _, _, Np, Kp = pick_weight_blocks(K, N, cfg.acc_len)
-    blockpad = lambda v: jnp.pad(v, ((0, Kp - K), (0, Np - N))).astype(jnp.int8)
-    pallas_w = blockpad(wq)
-    pallas_planes = (jnp.stack([blockpad(p) for p in planes]) if planes
-                     else jnp.zeros((0, Kp, Np), jnp.int8))
-
+    pad = ((0, Kp - K), (0, Np - N))
     return PackedCimWeights(
         scale=scale,
-        sign=sign.astype(jnp.int8),
-        mag=mag.astype(jnp.int8),
-        gemm_w=gemm_w,
-        gemm_planes=gemm_planes,
-        pallas_w=pallas_w,
-        pallas_planes=pallas_planes,
+        pallas_w=jnp.pad(wq, pad).astype(jnp.int8),
+        pallas_planes=jnp.pad(fold_dcim_planes(wq, cfg),
+                              ((0, 0),) + pad).astype(jnp.int8),
         k_dim=K,
         n_dim=N,
         cfg=cfg,
@@ -313,40 +280,40 @@ def packed_cim_matmul_int(
             "Re-pack the weights for the serving config, or serve an "
             "all-analog subset (n_dcim_products=0, same n_mag_bits and "
             "acc_len), which never touches the folded planes.")
+    if fidelity != "fast":
+        # cold-path fidelities cut the raw ints from the tiles (one O(K*N)
+        # convert, dwarfed by their own per-bit-product work)
+        return cim_matmul_int(x_q, packed.wq(), macro, cfg, noise_key,
+                              fidelity, use_pallas=use_pallas,
+                              noise_segments=noise_segments)
+    # analog-subset serving of a hybrid pack: no activation bit planes,
+    # so both fast routes get a zero-plane weight operand
+    x_bits = tuple(_dcim_by_j(cfg))
+    planes = packed.pallas_planes[:len(x_bits)]
     # the Pallas kernel implements the NOMINAL macro only: with a fault
     # model armed (resilience/faults), the drifted conversion epilogue
     # exists solely in the XLA fast path, so route there
-    if (fidelity == "fast" and noise_key is None
-            and _prepacked_kernel_supported(cfg)
+    if (noise_key is None and _prepacked_kernel_supported(cfg)
             and not rfaults.active()):
         if use_pallas is None:
             use_pallas = jax.default_backend() == "tpu"
         if use_pallas:
             from ..kernels.ccim_matmul.ops import ccim_matmul_int_prepacked
-            x_bits = tuple(_dcim_by_j(cfg))
-            # analog-subset serving of a hybrid pack: no activation bit
-            # planes, so hand the kernel a zero-plane weight operand
-            planes = (packed.pallas_planes if packed.cfg == cfg
-                      else packed.pallas_planes[:len(x_bits)])
             return ccim_matmul_int_prepacked(
                 x_q, packed.pallas_w, planes,
                 k_dim=packed.k_dim, n_dim=packed.n_dim,
                 acc_len=cfg.acc_len, x_bits=x_bits,
                 dcim_lsb=cfg.dcim_lsb, adc_bits=cfg.adc_bits,
                 use_pallas=True)
-    if fidelity == "fast":
-        C = packed.gemm_w.shape[0]
-        pad = C * cfg.acc_len - K
-        xq = jnp.pad(x_q, ((0, 0), (0, pad))).reshape(M, C, cfg.acc_len)
-        return hybrid_mac_fast_gemm_prepacked(
-            xq, packed.gemm_w, packed.gemm_planes, noise_key, cfg,
-            noise_segments=noise_segments, chunk_block=chunk_block,
-        ) * cfg.dcim_lsb
-    # cold-path fidelities reconstruct the raw ints (one O(K*N) multiply,
-    # dwarfed by their own per-bit-product work)
-    return cim_matmul_int(x_q, packed.wq(), macro, cfg, noise_key, fidelity,
-                          use_pallas=use_pallas,
-                          noise_segments=noise_segments)
+    wf, w_pl = fast_gemm_weight_ops(packed.pallas_w, planes, K,
+                                    packed.n_dim, cfg.acc_len)
+    C = wf.shape[0]
+    xq = jnp.pad(x_q, ((0, 0), (0, C * cfg.acc_len - K))).reshape(
+        M, C, cfg.acc_len)
+    return hybrid_mac_fast_gemm_prepacked(
+        xq, wf, w_pl, noise_key, cfg,
+        noise_segments=noise_segments, chunk_block=chunk_block,
+    ) * cfg.dcim_lsb
 
 
 def packed_cim_matmul(

@@ -292,8 +292,8 @@ def pack_cim_params(params: Params, cfg: ModelConfig) -> Params:
     """Replace every _dense-consumed projection with PackedCimWeights.
 
     This is the software analogue of writing the SRAM arrays: per-channel
-    SMF scales, integer sign/magnitude contents and folded MSB bit-planes
-    are computed ONCE here; prefill/decode then run activation-only
+    SMF scales, integer weights and folded MSB bit-planes are computed
+    ONCE here, as int8 tiles; prefill/decode then run activation-only
     quantization.  Stacked (scanned) layer weights are packed under vmap,
     so the packed leaves keep their leading layer axis and drop straight
     into the scanned stacks.  Bit-identical to unpacked cim_mode execution.

@@ -268,12 +268,13 @@ def apply_weight_faults(model: FaultModel, params):
     """Pure transform of a (packed) params tree: every PackedCimWeights
     leaf gets ``stuck_frac`` of its bit-cells faulted, deterministically
     keyed by (seed, leaf path), and is RE-packed from the faulted ints --
-    so the fast-GEMM copies, Pallas tiles and ``wq()`` reconstruction all
-    agree on the faulted array contents, exactly like silicon where every
-    execution path reads the same cells.  Non-packed leaves pass through
-    untouched (stuck-at faults are a property of the CIM array).
+    the bit cells are ``split_sign_mag(leaf.wq())``, and every execution
+    route derives its operands from the one re-packed layout, exactly like
+    silicon where every path reads the same cells.  Non-packed leaves pass
+    through untouched (stuck-at faults are a property of the CIM array).
     """
     # function-level import: core.ccim imports this module at load time
+    from ..core.ccim import split_sign_mag
     from ..core.engine import (FusedPackedCimWeights, PackedCimWeights,
                                pack_quantized_cim_weights)
 
@@ -287,7 +288,7 @@ def apply_weight_faults(model: FaultModel, params):
         if isinstance(leaf, FusedPackedCimWeights):
             return dataclasses.replace(leaf, packed=fix(path, leaf.packed))
         if isinstance(leaf, PackedCimWeights):
-            wq = faulted_wq(model, leaf.sign, leaf.mag, tag(path),
+            wq = faulted_wq(model, *split_sign_mag(leaf.wq()), tag(path),
                             n_mag_bits=leaf.cfg.n_mag_bits)
             repack = lambda w, s: pack_quantized_cim_weights(
                 w, s, leaf.cfg)

@@ -274,7 +274,7 @@ def first_packed_leaf(params):
         if isinstance(leaf, PackedCimWeights):
             # scanned layer stacks pack with a leading depth axis; the
             # probe wants one physical array -- layer 0's
-            if leaf.sign.ndim == 3:
+            if leaf.pallas_w.ndim == 3:
                 leaf = jax.tree_util.tree_map(lambda a: a[0], leaf)
             return leaf
     return None

@@ -111,9 +111,8 @@ def test_trace_static_leak_fires_on_array_in_meta():
     from repro.core.engine import PackedCimWeights
     z = jnp.zeros((2, 2), jnp.int8)
     leaky = PackedCimWeights(
-        scale=jnp.ones((1, 2)), sign=z, mag=z, gemm_w=jnp.zeros((1, 2, 2)),
-        gemm_planes=jnp.zeros((1, 2, 2)), pallas_w=z,
-        pallas_planes=jnp.zeros((1, 2, 2)),
+        scale=jnp.ones((1, 2)), pallas_w=z,
+        pallas_planes=jnp.zeros((1, 2, 2), jnp.int8),
         k_dim=2, n_dim=2, cfg=jnp.zeros((1,)))   # <- array in a meta slot
     rep = AnalysisReport()
     AT.check_static_keys(_BadCfg(knobs=[]), {"w": leaky}, rep)

@@ -82,12 +82,36 @@ def test_packed_through_jit_and_vmap():
     # stacked packing (the scanned-layer-stack shape)
     ws = jnp.stack([w, 2 * w, -w])
     ps = jax.vmap(lambda v: pack_cim_weights(v, CFG))(ws)
-    assert ps.mag.shape[0] == 3
+    assert ps.pallas_w.shape[0] == 3
     one = jax.tree.map(lambda v: v[1], ps)
     ref = pack_cim_weights(2 * w, CFG)
-    np.testing.assert_array_equal(np.asarray(one.mag), np.asarray(ref.mag))
+    np.testing.assert_array_equal(np.asarray(one.wq()), np.asarray(ref.wq()))
     np.testing.assert_array_equal(np.asarray(one.pallas_w),
                                   np.asarray(ref.pallas_w))
+
+
+@pytest.mark.parametrize("knobs", [{}, dict(n_dcim_products=0),
+                                   dict(acc_len=32)],
+                         ids=["default", "all_analog", "acc_len32"])
+def test_pack_stores_only_the_int8_tiles(knobs):
+    """A pack stores one layout, the kernels' block-padded int8 weight tile
+    and its folded planes: at most (1 + n_j) bytes a padded weight plus
+    the scale.  Every other route derives its operands from them."""
+    from repro.core.ccim import _dcim_by_j
+    from repro.kernels.ccim_matmul.ops import pick_weight_blocks
+    cfg = dataclasses.replace(CFG, **knobs)
+    _, w = _xw(seed=13, k=100, n=24)
+    p = pack_cim_weights(w, cfg)
+    leaves = jax.tree_util.tree_leaves_with_path(p)
+    assert [jax.tree_util.keystr(k) for k, _ in leaves] == [
+        ".scale", ".pallas_w", ".pallas_planes"]
+    assert p.pallas_w.dtype == p.pallas_planes.dtype == jnp.int8
+    n_j = len(_dcim_by_j(cfg))
+    _, _, Np, Kp = pick_weight_blocks(100, 24, cfg.acc_len)
+    assert p.pallas_planes.shape == (n_j, Kp, Np)
+    assert (sum(a.nbytes for _, a in leaves)
+            <= (1 + n_j) * Kp * Np + p.scale.nbytes)
+    assert p.wq().shape == (100, 24)
 
 
 # ---------------------------------------------------------------------------
@@ -251,7 +275,7 @@ def test_pack_cim_params_structure():
     assert isinstance(blk["attn"]["wo"], PackedCimWeights)
     assert isinstance(blk["mlp"]["w2"], PackedCimWeights)
     # stacked leading layer axis survives packing (scan-sliceable)
-    assert qkv.packed.mag.shape[0] == cfg.n_layers
+    assert qkv.packed.pallas_w.shape[0] == cfg.n_layers
     # non-projection leaves stay float
     assert not isinstance(params["embed"], PackedCimWeights)
     assert not isinstance(blk["ln1"], PackedCimWeights)

@@ -118,7 +118,7 @@ def test_mixed_pack_structure_and_config_meta():
     kv = blk["attn"]["wk+wv"]
     assert isinstance(kv, FusedPackedCimWeights)
     assert kv.packed.cfg.acc_len == 32                    # plan default
-    assert blk["attn"]["wq"].mag.shape[0] == cfg.n_layers  # scan axis kept
+    assert blk["attn"]["wq"].pallas_w.shape[0] == cfg.n_layers  # scan axis
 
 
 def test_planned_forward_packed_matches_unpacked_incl_noise():

@@ -183,7 +183,7 @@ def test_stuck_weight_faults_seeded(packed_cim):
     f1 = F.apply_weight_faults(m, params)
     f2 = F.apply_weight_faults(m, params)
     a, b = first_packed_leaf(f1), first_packed_leaf(f2)
-    np.testing.assert_array_equal(np.asarray(a.mag), np.asarray(b.mag))
+    np.testing.assert_array_equal(np.asarray(a.wq()), np.asarray(b.wq()))
     orig = first_packed_leaf(params)
     wq0, wq1 = np.asarray(orig.wq()), np.asarray(a.wq())
     frac = np.mean(wq0 != wq1)
